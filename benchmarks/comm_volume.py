@@ -10,7 +10,9 @@ ops inside the step; (a) is measured directly, (b) rides in the step time.
 
 The transport sweep needs a multi-device mesh, so it re-execs this module
 in a subprocess with ``--xla_force_host_platform_device_count=4`` and
-merges the child's JSON into ``experiments/comm_volume.json``.
+merges the child's JSON into ``experiments/comm_volume.json``.  The child
+counts bytes and never times anything, so it runs with
+``JAX_PLATFORMS=cpu``: on a chip host the parent holds the TPU.
 ``REPRO_BENCH_TINY=1`` shrinks both parts for CI smoke runs.
 """
 from __future__ import annotations
@@ -101,7 +103,7 @@ def transport_sweep(tiny: bool, transports=("allgather", "p2p")) -> dict:
     from repro.data import make_task
     from repro.dist import TrainSpec, init_caches
     from repro.dist.capgnn_spmd import make_spmd_runtime
-    from repro.launch.dryrun import collective_bytes
+    from repro.launch.hlo_cost import collective_bytes
     from repro.models.gnn import init_gnn
     from repro.optim import adam as mk_adam
 
@@ -187,7 +189,7 @@ def strategy_sweep(tiny: bool) -> dict:
     from repro.dist.strategy_15d import (build_spmm15d_layout,
                                          make_spmm15d_runtime,
                                          train_spmm15d)
-    from repro.launch.dryrun import collective_bytes
+    from repro.launch.hlo_cost import collective_bytes
     from repro.models.gnn import init_gnn
     from repro.optim import adam as mk_adam
 
@@ -288,6 +290,7 @@ def strategy_model_sweep(task, parts_list=(2, 4, 8, 16)) -> dict:
 def _strategy_sweep_subprocess(tiny: bool) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"   # counts only; the parent holds the chip
     env["REPRO_BENCH_TINY"] = "1" if tiny else "0"
     env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..", "src")
                          + os.pathsep + env.get("PYTHONPATH", ""))
@@ -306,6 +309,7 @@ def _transport_sweep_subprocess(tiny: bool,
                                 transports=("allgather", "p2p")) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"   # counts only; the parent holds the chip
     env["REPRO_BENCH_TINY"] = "1" if tiny else "0"
     env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..", "src")
                          + os.pathsep + env.get("PYTHONPATH", ""))

@@ -30,7 +30,9 @@ Four sections:
   ``--xla_force_host_platform_device_count=4`` and runs the shard_map
   runtime in host mode over both halo transports (``p2p`` ring /
   ``allgather``) under a combined fault spec, asserting the same
-  injected==defended accounting on each.
+  injected==defended accounting on each.  The child counts and times
+  nothing, so it runs with ``JAX_PLATFORMS=cpu`` (on a chip host the
+  parent holds the TPU).
 
 ``REPRO_BENCH_TINY=1`` shrinks everything for CI smoke runs.
 """
@@ -317,6 +319,7 @@ def spmd_sweep(tiny: bool, transports=("allgather", "p2p")) -> dict:
 def _spmd_subprocess(tiny: bool, transports=("allgather", "p2p")) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"   # counts only; the parent holds the chip
     env["REPRO_BENCH_TINY"] = "1" if tiny else "0"
     env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..", "src")
                          + os.pathsep + env.get("PYTHONPATH", ""))

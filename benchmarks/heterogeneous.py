@@ -27,7 +27,9 @@ effects; the cost-model section keeps reddit for continuity.)
 A subprocess with ``--xla_force_host_platform_device_count=4`` (same
 pattern as ``benchmarks.comm_volume``) drives the compiled SPMD step for
 the uneven partitions over BOTH halo transports and checks the wire-row
-accounting and cross-transport loss agreement.  ``REPRO_BENCH_TINY=1``
+accounting and cross-transport loss agreement.  It counts rows and times
+nothing, so it runs with ``JAX_PLATFORMS=cpu`` (on a chip host the parent
+holds the TPU).  ``REPRO_BENCH_TINY=1``
 shrinks every graph for CI smoke runs.
 """
 from __future__ import annotations
@@ -301,6 +303,7 @@ def straggler_transport_child(tiny: bool) -> dict:
 def _transport_child_subprocess(tiny: bool) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"   # counts only; the parent holds the chip
     env["REPRO_BENCH_TINY"] = "1" if tiny else "0"
     env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..", "src")
                          + os.pathsep + env.get("PYTHONPATH", ""))

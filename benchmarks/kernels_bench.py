@@ -4,8 +4,8 @@ side), plus ELL pack statistics before/after RAPA pruning and an
 end-to-end aggregation-backend sweep (edges vs Pallas ell/hybrid through
 the stacked runtime — logit parity + per-step wall time).
 
-``REPRO_BENCH_TINY=1`` shrinks the task for CI smoke runs (the Pallas
-interpret path is exercised either way).
+``REPRO_BENCH_TINY=1`` shrinks the task for CI smoke runs.  The Pallas
+kernels follow the platform: compiled on a TPU, interpreted elsewhere.
 """
 from __future__ import annotations
 
@@ -102,7 +102,7 @@ def run(out_dir: str = DEFAULT_OUT, tiny: bool | None = None) -> dict:
             h = np.random.default_rng(0).normal(
                 size=(part.n_local, 64)).astype(np.float32)
             out = ell_spmm(jnp.asarray(cols), jnp.asarray(vals),
-                           jnp.asarray(h), interpret=True)
+                           jnp.asarray(h))
             want = R.ell_spmm_ref(jnp.asarray(cols), jnp.asarray(vals),
                                   jnp.asarray(h))
             err = float(np.abs(np.asarray(out) - np.asarray(want)).max())

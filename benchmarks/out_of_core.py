@@ -22,7 +22,9 @@ Three sections:
   in host mode over both halo transports, asserting plan-counted host
   fetch rows/bytes == the store's consumed staged rows/bytes exactly
   (the identity :meth:`~repro.dist.ExchangePlan.host_fetch_rows`
-  promises), plus the d2h writeback bytes of every emit step.
+  promises), plus the d2h writeback bytes of every emit step.  The
+  child counts and times nothing, so it runs with ``JAX_PLATFORMS=cpu``
+  (on a chip host the parent holds the TPU).
 
 ``REPRO_BENCH_TINY=1`` shrinks everything for CI smoke runs.
 """
@@ -300,6 +302,7 @@ def _accounting_subprocess(tiny: bool,
                            transports=("allgather", "p2p")) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"   # counts only; the parent holds the chip
     env["REPRO_BENCH_TINY"] = "1" if tiny else "0"
     env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..", "src")
                          + os.pathsep + env.get("PYTHONPATH", ""))

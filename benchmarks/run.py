@@ -141,6 +141,8 @@ def main() -> None:
                          "tracer and attach Perfetto trace artifacts "
                          "(experiments/trace_<suite>.json) per suite")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.trace:
         os.environ["REPRO_BENCH_TRACE"] = "1"
     names: list[str] = []

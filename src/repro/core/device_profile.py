@@ -146,7 +146,7 @@ def measure_profile(size: int = 1024, sparsity: float = 0.996,
         jax.device_get(buf)
     d2h = (time.perf_counter() - t0) / repeats
     idt = timed(jax.jit(lambda x: x + 0.0), a)
-    mem = _backend_mem_gib(jax, default=16.0)
+    mem = _backend_mem_gib()
     return DeviceProfile("measured", mm, spmm, h2d, d2h, idt, mem,
                          host_mem_gib=detect_host_mem_gib())
 
@@ -172,14 +172,11 @@ def detect_host_mem_gib(default: float = 16.0) -> float:
         return default
 
 
-def _backend_mem_gib(jax, default: float) -> float:
-    """Device memory in GiB from the backend, ``default`` if unavailable
-    (CPU backends typically expose no memory stats)."""
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-        limit = (stats or {}).get("bytes_limit", 0)
-        if limit:
-            return float(limit) / 1024.0 ** 3
-    except Exception:
-        pass
-    return default
+def _backend_mem_gib() -> float:
+    """Device memory in GiB: the backend's ``bytes_limit``.  The host CPU
+    backend reports none; its devices share the host RAM."""
+    from repro.obs.tracer import device_memory_stats
+    stats = device_memory_stats()
+    if stats is None:
+        return detect_host_mem_gib()
+    return float(stats["bytes_limit"]) / 1024.0 ** 3

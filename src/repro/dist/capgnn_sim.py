@@ -185,11 +185,18 @@ def check_backend(sp: StackedParts, backend: str) -> None:
             f"stack_partitions(ps, task, backend={backend!r})")
 
 
-def make_adj_builder(sp: StackedParts, backend: str, interpret: bool = True):
-    """Return ``(pack_leaves, build)``: ``pack_leaves`` is a dict of
-    per-partition ``[P, ...]`` arrays to map over (vmap in the oracle, shard
-    in SPMD), and ``build(leaves)`` constructs one partition's
-    :class:`~repro.models.gnn.Adjacency` from the corresponding slices.
+def make_adj_builder(sp: StackedParts, backend: str, stacked: bool = False):
+    """Return ``(pack_leaves, build)``: ``build(pack_leaves)`` constructs
+    the :class:`~repro.models.gnn.Adjacency` (inside a trace, where the
+    leaves arrive as arguments).
+
+    By default ``pack_leaves`` holds per-partition ``[P, ...]`` arrays to
+    shard over a mesh, and ``build`` takes one partition's slices.  With
+    ``stacked=True`` the P partitions form one block-diagonal adjacency
+    over the flattened stacked rows — ``P*NI`` inner rows, then ``P*NH``
+    halo rows (:func:`stacked_rows`) — so a device holding every
+    partition runs one unbatched SpMM: a vmapped segment-sum lowers to a
+    batched scatter whose TPU compile time grows with the edge count.
 
     Every backend aggregates over the identical edge set (the packs are
     built from the same remapped edge lists at stack time), so swapping the
@@ -197,32 +204,56 @@ def make_adj_builder(sp: StackedParts, backend: str, interpret: bool = True):
     byte accounting are backend-invariant.
     """
     check_backend(sp, backend)
-    ni, nh = sp.n_inner_max, sp.n_halo_max
+    p, ni, nh = sp.num_parts, sp.n_inner_max, sp.n_halo_max
+    n_rows, n_cols = (p * ni, p * (ni + nh)) if stacked else (ni, ni + nh)
+
+    def cols(c):
+        if not stacked:
+            return jnp.asarray(c)
+        part = np.arange(p).reshape((p,) + (1,) * (c.ndim - 1))
+        flat = np.where(c < ni, part * ni + c, p * ni + part * nh + c - ni)
+        return jnp.asarray(flat.reshape((-1,) + c.shape[2:]), jnp.int32)
+
+    def rows(r):
+        # padding rows (== NI) stay out of range, so the scatter drops them
+        if not stacked:
+            return jnp.asarray(r)
+        part = np.arange(p)[:, None]
+        return jnp.asarray(np.where(r < ni, part * ni + r, n_rows).reshape(-1),
+                           jnp.int32)
+
+    def vals(v):
+        v = np.asarray(v)
+        return jnp.asarray(v.reshape((-1,) + v.shape[2:]) if stacked else v)
+
     if backend == "edges":
-        leaves = {"src": jnp.asarray(sp.e_src), "dst": jnp.asarray(sp.e_dst),
-                  "w": jnp.asarray(sp.e_w)}
+        leaves = {"src": cols(sp.e_src), "dst": rows(sp.e_dst),
+                  "w": vals(sp.e_w)}
 
         def build(lv):
-            return EdgeListAdj(lv["src"], lv["dst"], lv["w"], ni, ni + nh)
+            return EdgeListAdj(lv["src"], lv["dst"], lv["w"], n_rows, n_cols)
     elif backend == "ell":
-        leaves = {"cols": jnp.asarray(sp.ell.cols),
-                  "vals": jnp.asarray(sp.ell.vals)}
+        leaves = {"cols": cols(sp.ell.cols), "vals": vals(sp.ell.vals)}
 
         def build(lv):
-            return EllAdj(lv["cols"], lv["vals"], ni + nh,
-                          interpret=interpret)
+            return EllAdj(lv["cols"], lv["vals"], n_cols)
     else:  # hybrid
-        leaves = {"cols": jnp.asarray(sp.ell.cols),
-                  "vals": jnp.asarray(sp.ell.vals),
-                  "tail_src": jnp.asarray(sp.ell.tail_src),
-                  "tail_dst": jnp.asarray(sp.ell.tail_dst),
-                  "tail_w": jnp.asarray(sp.ell.tail_w)}
+        leaves = {"cols": cols(sp.ell.cols), "vals": vals(sp.ell.vals),
+                  "tail_src": cols(sp.ell.tail_src),
+                  "tail_dst": rows(sp.ell.tail_dst),
+                  "tail_w": vals(sp.ell.tail_w)}
 
         def build(lv):
             return HybridAdj(lv["cols"], lv["vals"], lv["tail_src"],
-                             lv["tail_dst"], lv["tail_w"], ni + nh,
-                             interpret=interpret)
+                             lv["tail_dst"], lv["tail_w"], n_cols)
     return leaves, build
+
+
+def stacked_rows(h: jnp.ndarray, halo: jnp.ndarray) -> jnp.ndarray:
+    """``[P, NI, d]`` inner + ``[P, NH, d]`` halo -> the ``[P*NI + P*NH,
+    d]`` row space of a ``stacked=True`` adjacency."""
+    d = h.shape[-1]
+    return jnp.concatenate([h.reshape(-1, d), halo.reshape(-1, d)], axis=0)
 
 
 # ---------------------------------------------------------------------------
@@ -285,6 +316,8 @@ class SimRuntime:
     # the TrainSpec this runtime was configured from (always set — the
     # loose-kwarg shim synthesises one), recorded into TrainReport.spec
     spec: TrainSpec | None = dataclasses.field(default=None, repr=False)
+    # the stacked inputs every jitted step takes as its last argument
+    data: dict | None = dataclasses.field(default=None, repr=False)
 
     def padding_stats(self) -> dict:
         """Valid vs padded stacked-row counts (see
@@ -346,7 +379,8 @@ class SimRuntime:
         else:
             xe = exchange_arrays(new_xplan)
             out = self.jit_steps["pipelined"](params, opt_state, caches,
-                                              self._state["xarr"], xe)
+                                              self._state["xarr"], xe,
+                                              self.data)
             self._state["xarr"] = xe
         self.xplan = new_xplan
         return out
@@ -360,14 +394,15 @@ class SimRuntime:
             hd = self._state["_dummy_hostd"](name)
             return self.jit_steps[name].lower(params, opt_state, caches,
                                               hd, self._state["l0loc"],
-                                              xa, xa)
-        return self.jit_steps[name].lower(params, opt_state, caches, xa, xa)
+                                              xa, xa, self.data)
+        return self.jit_steps[name].lower(params, opt_state, caches, xa, xa,
+                                          self.data)
 
 
 def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
                      opt: Optimizer, exchange_layer0: bool = True,
-                     backend: str = "edges", interpret: bool = True,
-                     halo_dtype=None, donate: bool = True,
+                     backend: str = "edges", halo_dtype=None,
+                     donate: bool = True,
                      features: str = "device",
                      host_store: HostFeatureStore | None = None,
                      prefetch_depth: int = 2,
@@ -421,13 +456,11 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
                          features=features,
                          halo_dtype=halo_dtype_name(halo_dtype),
                          exchange_layer0=exchange_layer0, donate=donate,
-                         interpret=interpret,
                          prefetch_depth=prefetch_depth)
     # the spec is authoritative from here on — identical construction for
     # both entry paths (the shim-equivalence tests pin this)
     exchange_layer0 = spec.exchange_layer0
     backend = spec.backend
-    interpret = spec.interpret
     halo_dtype = spec.halo_dtype
     donate = spec.donate
     features = spec.features
@@ -453,17 +486,21 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
     masks = {k: jnp.asarray(m).reshape(-1)
              for k, m in (("train", sp.train_mask), ("val", sp.val_mask),
                           ("test", sp.test_mask))}
-    adj_leaves, build_adj = make_adj_builder(sp, backend, interpret)
+    adj_leaves, build_adj = make_adj_builder(sp, backend, stacked=True)
+    # the stacked inputs travel into every jitted function as an argument:
+    # captured, they would be baked into each executable as constants
+    data = {"feats": feats, "labels": labels, "masks": masks,
+            "adj": adj_leaves}
+    if not host_mode:
+        data["halo_feats"] = halo_feats
 
-    def layer_all(lp, h, halo, is_last):
-        def one(lv, hi, hhi):
-            adj = build_adj(lv)
-            h_local = jnp.concatenate([hi, hhi], axis=0)
-            with device_scope("spmm_layer"):
-                return _layer_apply(cfg, lp, adj, h_local, ni, is_last)
-        return jax.vmap(one)(adj_leaves, h, halo)
+    def layer_all(lp, h, halo, is_last, adj_lv):
+        with device_scope("spmm_layer"):
+            out = _layer_apply(cfg, lp, build_adj(adj_lv),
+                               stacked_rows(h, halo), p * ni, is_last)
+        return out.reshape(p, ni, -1)
 
-    def forward(params, caches, xr, xe, use_stale: bool,
+    def forward(params, caches, xr, xe, use_stale: bool, data,
                 hostd=None, l0loc=None):
         """``xr`` is the installed (read) plan: stale caches are scattered
         at its positions and its uncached tier is exchanged.  ``xe`` is the
@@ -478,7 +515,7 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
         fetch) scattered at the host tier's positions (uncached ∪ global
         membership).  Stale global reads come from ``hostd["gl"]`` — the
         staged host-resident buffers — rather than a device cache."""
-        h = feats
+        h = data["feats"]
         fresh = {"local": [], "global": []}
         for li, lp in enumerate(params):
             if li == 0:
@@ -491,7 +528,7 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
                                     hostd["l0"].astype(h.dtype),
                                     xr["host"]["feat_valid"])
                 else:
-                    halo = halo_feats
+                    halo = data["halo_feats"]
             else:
                 d = h.shape[-1]
                 halo = jnp.zeros((p, nh, d), h.dtype)
@@ -518,21 +555,23 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
                 fresh["local"].append(loc_fresh)
                 fresh["global"].append(buf_fresh)
             with device_scope(f"layer{li}"):
-                h = layer_all(lp, h, halo, is_last=(li == layers - 1))
+                h = layer_all(lp, h, halo, (li == layers - 1), data["adj"])
         return h, fresh
 
-    def loss_fn(params, caches, xr, xe, use_stale: bool,
+    def loss_fn(params, caches, xr, xe, use_stale: bool, data,
                 hostd=None, l0loc=None):
-        logits, fresh = forward(params, caches, xr, xe, use_stale,
+        logits, fresh = forward(params, caches, xr, xe, use_stale, data,
                                 hostd, l0loc)
         flat = logits.reshape(-1, logits.shape[-1])
-        loss = cross_entropy_loss(flat, labels, masks["train"])
+        loss = cross_entropy_loss(flat, data["labels"],
+                                  data["masks"]["train"])
         return loss, (flat, fresh)
 
     def _metrics_and_caches(loss, flat, fresh, caches, stale_gl,
-                            use_stale: bool, emit_fresh: bool):
+                            use_stale: bool, emit_fresh: bool, data):
         metrics = {"loss": loss,
-                   "acc": accuracy(flat, labels, masks["train"])}
+                   "acc": accuracy(flat, data["labels"],
+                                   data["masks"]["train"])}
         # Drift compares fresh rows against the stale source of this step.
         # In host mode that source is the staged host buffer (``stale_gl``
         # from hostd) — on a host *refresh* there is no staged stale
@@ -566,16 +605,16 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
 
     def make_step(use_stale: bool, emit_fresh: bool):
         if host_mode:
-            def step(params, opt_state, caches, hostd, l0loc, xr, xe):
+            def step(params, opt_state, caches, hostd, l0loc, xr, xe, data):
                 (loss, (flat, fresh)), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(params, caches, xr, xe,
-                                           use_stale, hostd, l0loc)
+                                           use_stale, data, hostd, l0loc)
                 new_params, new_state = opt.update(grads, opt_state, params)
                 stale_gl = ([g.astype(jnp.float32) for g in hostd["gl"]]
                             if use_stale else [])
                 metrics, out_caches = _metrics_and_caches(
                     loss, flat, fresh, caches, stale_gl,
-                    use_stale, emit_fresh)
+                    use_stale, emit_fresh, data)
                 if emit_fresh:
                     # emitted global buffers go back to the host store
                     # (d2h writeback by the caller), not into device caches
@@ -588,13 +627,14 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
             return jax.jit(step,
                            donate_argnums=(0, 1, 2) if donate else ())
 
-        def step(params, opt_state, caches, xr, xe):
+        def step(params, opt_state, caches, xr, xe, data):
             (loss, (flat, fresh)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params, caches, xr, xe, use_stale)
+                loss_fn, has_aux=True)(params, caches, xr, xe, use_stale,
+                                       data)
             new_params, new_state = opt.update(grads, opt_state, params)
             metrics, out_caches = _metrics_and_caches(
                 loss, flat, fresh, caches, caches["global"],
-                use_stale, emit_fresh)
+                use_stale, emit_fresh, data)
             return new_params, new_state, out_caches, metrics
         # steady-state steps rewrite (params, opt_state, caches) in place;
         # the exchange arrays (xr, xe) are NOT donated — they are reused
@@ -604,13 +644,13 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
     caches0 = init_caches(cfg, xplan, p, features=features)
 
     if host_mode:
-        def _fwd_fresh(params, hostd, l0loc, xr):
-            logits, _ = forward(params, caches0, xr, xr, False,
+        def _fwd_fresh(params, hostd, l0loc, xr, data):
+            logits, _ = forward(params, caches0, xr, xr, False, data,
                                 hostd, l0loc)
             return logits
     else:
-        def _fwd_fresh(params, xr):
-            logits, _ = forward(params, caches0, xr, xr, False)
+        def _fwd_fresh(params, xr, data):
+            logits, _ = forward(params, caches0, xr, xr, False, data)
             return logits
 
     jit_steps = {"refresh": make_step(False, True),
@@ -626,7 +666,8 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
         def stepper(params, opt_state, caches):
             xa = state["xarr"]
             with host_annotation(ann):
-                return jit_steps[name](params, opt_state, caches, xa, xa)
+                return jit_steps[name](params, opt_state, caches, xa, xa,
+                                       data)
         return stepper
 
     if host_mode:
@@ -738,7 +779,7 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
                 xa = state["xarr"]
                 with host_annotation(ann):
                     out = jit_steps[name](params, opt_state, caches, hostd,
-                                          state["l0loc"], xa, xa)
+                                          state["l0loc"], xa, xa, data)
                 if emit:
                     new_p, new_s, out_caches, host_out, metrics = out
                     with tr.span("writeback"):
@@ -775,7 +816,7 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
             with host_annotation("capgnn/step_transition"):
                 new_p, new_s, out_caches, host_out, metrics = (
                     jit_steps["pipelined"](params, opt_state, caches, hostd,
-                                           state["l0loc"], xr, xe))
+                                           state["l0loc"], xr, xe, data))
             state["xarr"] = xe
             state["hostnp"] = _host_np(new_xp)
             # ...while the emitted buffers carry the NEW plan's membership
@@ -804,13 +845,13 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
             sf = _stage_l0()
             store.account_fetch(sf)
             return jit_steps["forward"](params, {"l0": sf.array},
-                                        state["l0loc"], state["xarr"])
+                                        state["l0loc"], state["xarr"], data)
 
         step_wrap = wrap_host
         _prefetch_l0()
     else:
         def forward_fresh(params):
-            return jit_steps["forward"](params, state["xarr"])
+            return jit_steps["forward"](params, state["xarr"], data)
 
         step_wrap = wrap
 
@@ -836,7 +877,7 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
                       halo_dtype_bytes=hd_bytes,
                       features=features, host_store=store,
                       jit_steps=jit_steps, _state=state, stacked=sp,
-                      spec=spec)
+                      spec=spec, data=data)
 
 
 # ---------------------------------------------------------------------------

@@ -37,9 +37,6 @@ latency.  Loss and gradient reductions are ``psum`` over the same axis
 tuple, so backprop through the exchange (``all_gather`` transpose /
 inverse-permutation ``ppermute``) reproduces the oracle's exact
 cross-partition gradient flow.
-
-Version note: ``shard_map`` is imported from ``jax.experimental.shard_map``
-for compatibility with pre-``jax.shard_map`` releases.
 """
 from __future__ import annotations
 
@@ -52,11 +49,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
-
-if hasattr(jax, "shard_map"):            # jax >= 0.5 exports it at top level
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map
 
 from repro.kernels.ops import pack_rows
 from repro.models.gnn import GNNConfig, _layer_apply, accuracy, cross_entropy_loss
@@ -76,7 +68,8 @@ __all__ = ["make_spmd_runtime", "SpmdRuntime", "TRANSPORTS",
 TRANSPORTS = ("allgather", "p2p")
 
 
-def spmd_exchange_arrays(xplan: ExchangePlan, p2p: bool,
+def spmd_exchange_arrays(xplan: ExchangePlan, p2p: bool, mesh,
+                         axis_names: tuple,
                          include_host: bool = False) -> dict:
     """One plan's exchange index arrays in the SPMD runtime's layout:
     ``"sh"`` leaves are ``[P, ...]`` and sharded over the partition axis,
@@ -84,7 +77,9 @@ def spmd_exchange_arrays(xplan: ExchangePlan, p2p: bool,
     The jitted steps take this pytree as a traced argument, so a
     capacity-padded re-plan swaps in without retracing.  ``include_host``
     adds the layer-0 host-tier scatter program (sharded like the other
-    per-worker tiers) for the ``features="host"`` runtimes."""
+    per-worker tiers) for the ``features="host"`` runtimes.  The leaves
+    are placed once on ``mesh`` (sharded over ``axis_names``), so no step
+    call reshards them."""
 
     def tier_arrays(t):
         d = {"send_row": t.send_row,
@@ -113,7 +108,10 @@ def spmd_exchange_arrays(xplan: ExchangePlan, p2p: bool,
     rep = {"g_src_part": xplan.glob.src_part,
            "g_src_slot": xplan.glob.src_slot,
            "g_buf_valid": xplan.glob.buf_valid}
-    return jax.tree.map(jnp.asarray, {"sh": sh, "rep": rep})
+    return {"sh": jax.device_put(jax.tree.map(jnp.asarray, sh),
+                                 NamedSharding(mesh, P(axis_names))),
+            "rep": jax.device_put(jax.tree.map(jnp.asarray, rep),
+                                  NamedSharding(mesh, P()))}
 
 
 def _shift_perm(p: int, r: int) -> list:
@@ -208,6 +206,8 @@ class SpmdRuntime:
     # the TrainSpec this runtime was configured from (always set — the
     # loose-kwarg shim synthesises one), recorded into TrainReport.spec
     spec: TrainSpec | None = dataclasses.field(default=None, repr=False)
+    # the stacked per-partition inputs, placed one partition per device
+    data: dict | None = dataclasses.field(default=None, repr=False)
 
     def padding_stats(self) -> dict:
         """Valid vs padded stacked-row counts (see
@@ -248,7 +248,7 @@ class SpmdRuntime:
             hook(xplan)
         else:
             self._state["xarr"] = spmd_exchange_arrays(
-                xplan, p2p=self.transport == "p2p")
+                xplan, self.transport == "p2p", self.mesh, self.axis_names)
 
     def step_transition(self, params, opt_state, caches,
                         new_xplan: ExchangePlan):
@@ -261,9 +261,11 @@ class SpmdRuntime:
         if hook is not None:
             out = hook(params, opt_state, caches, new_xplan)
         else:
-            xe = spmd_exchange_arrays(new_xplan, p2p=self.transport == "p2p")
+            xe = spmd_exchange_arrays(new_xplan, self.transport == "p2p",
+                                      self.mesh, self.axis_names)
             out = self.jit_steps["pipelined"](params, opt_state, caches,
-                                              self._state["xarr"], xe)
+                                              self._state["xarr"], xe,
+                                              self.data)
             self._state["xarr"] = xe
         self.xplan = new_xplan
         return out
@@ -277,14 +279,15 @@ class SpmdRuntime:
             hd = self._state["_dummy_hostd"](name)
             return self.jit_steps[name].lower(params, opt_state, caches,
                                               hd, self._state["l0loc"],
-                                              xa, xa)
-        return self.jit_steps[name].lower(params, opt_state, caches, xa, xa)
+                                              xa, xa, self.data)
+        return self.jit_steps[name].lower(params, opt_state, caches, xa, xa,
+                                          self.data)
 
 
 def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
                       opt: Optimizer, mesh, axis: str | Sequence[str] = "data",
                       exchange_layer0: bool = True, backend: str = "edges",
-                      interpret: bool = True, transport: str = "allgather",
+                      transport: str = "allgather",
                       halo_dtype=None, donate: bool = True,
                       pallas_pack: bool = False, features: str = "device",
                       host_store: HostFeatureStore | None = None,
@@ -326,11 +329,10 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
                          transport=transport, features=features,
                          halo_dtype=halo_dtype_name(halo_dtype),
                          exchange_layer0=exchange_layer0, donate=donate,
-                         interpret=interpret, pallas_pack=pallas_pack,
+                         pallas_pack=pallas_pack,
                          prefetch_depth=prefetch_depth)
     exchange_layer0 = spec.exchange_layer0
     backend = spec.backend
-    interpret = spec.interpret
     transport = spec.transport
     halo_dtype = spec.halo_dtype
     donate = spec.donate
@@ -351,7 +353,7 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
                          f"the plan has {p} partitions")
     layers = cfg.num_layers
     total_train = float(np.maximum(sp.train_mask.sum(), 1.0))
-    adj_leaves, build_adj = make_adj_builder(sp, backend, interpret)
+    adj_leaves, build_adj = make_adj_builder(sp, backend)
     hdt, hd_bytes = halo_dtype_info(halo_dtype)
     p2p = transport == "p2p"
     host_mode = features == "host"
@@ -375,9 +377,14 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
     }
     if not host_mode:
         data_sh["halo_feats"] = sp.halo_feats
-    data_sh = jax.tree.map(jnp.asarray, data_sh)
+    # placed once, one partition per device: no step call reshards them
+    data_sh = jax.device_put(jax.tree.map(jnp.asarray, data_sh),
+                             NamedSharding(mesh, P(names)))
 
     caches_spec = {"local": P(names), "global": P()}
+    # donated caches alias the step's sharded cache outputs only when the
+    # compiled input is laid out the same way, so pin it
+    caches_sh = {k: NamedSharding(mesh, v) for k, v in caches_spec.items()}
     xarr_spec = {"sh": P(names), "rep": P()}
 
     def _quant(x):
@@ -412,8 +419,7 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
 
         def peer_ring(tier, h):
             payload = pack_rows(h, tier["peer_send_row"][0],
-                                use_pallas=pallas_pack,
-                                interpret=interpret)             # [P, B, d]
+                                use_pallas=pallas_pack)          # [P, B, d]
             payload = jnp.where(tier["peer_send_valid"][0][..., None],
                                 payload, 0.0)
             return _PeerRing(_quant(payload), i_dev, p, names)
@@ -612,30 +618,33 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
             out_specs = (P(), P(), host_caches_spec, mspec)
             if emit_fresh:
                 out_specs = (P(), P(), host_caches_spec, P(), mspec)
-            sm = shard_map(
+            sm = jax.shard_map(
                 device_step, mesh=mesh,
                 in_specs=(P(), P(), caches_spec, P(names), xarr_spec,
                           xarr_spec, hostd_spec, P(names)),
-                out_specs=out_specs, check_rep=False)
+                out_specs=out_specs, check_vma=False)
 
-            def step(params, opt_state, caches, hostd, l0loc, xr, xe):
-                return sm(params, opt_state, caches, data_sh, xr, xe,
+            def step(params, opt_state, caches, hostd, l0loc, xr, xe, dsh):
+                return sm(params, opt_state, caches, dsh, xr, xe,
                           hostd, l0loc)
             # the staged hostd payloads are single-use but never match an
             # output shape, so they are not donated (mirrors the oracle)
-            return jax.jit(step, donate_argnums=(0, 1, 2) if donate else ())
+            return jax.jit(step, donate_argnums=(0, 1, 2) if donate else (),
+                           in_shardings=(None, None, caches_sh) + (None,) * 5)
 
-        sm = shard_map(
+        sm = jax.shard_map(
             device_step, mesh=mesh,
             in_specs=(P(), P(), caches_spec, P(names), xarr_spec, xarr_spec),
             out_specs=(P(), P(), caches_spec, mspec),
-            check_rep=False)
+            check_vma=False)
 
-        def step(params, opt_state, caches, xr, xe):
-            return sm(params, opt_state, caches, data_sh, xr, xe)
+        def step(params, opt_state, caches, xr, xe, dsh):
+            return sm(params, opt_state, caches, dsh, xr, xe)
         # steady-state steps rewrite (params, opt_state, caches) in place;
-        # the exchange arrays (xr, xe) are reused across steps, not donated
-        return jax.jit(step, donate_argnums=(0, 1, 2) if donate else ())
+        # the exchange arrays (xr, xe) and the stacked inputs (dsh) are
+        # reused across steps, not donated
+        return jax.jit(step, donate_argnums=(0, 1, 2) if donate else (),
+                       in_shardings=(None, None, caches_sh) + (None,) * 3)
 
     if host_mode:
         def _device_fwd_fresh(params, caches, dsh, xr, hostd, l0loc):
@@ -643,31 +652,34 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
                                         hostd=hostd, l0loc=l0loc)
             return logits[None]
 
-        sm_fwd = shard_map(_device_fwd_fresh, mesh=mesh,
-                           in_specs=(P(), caches_spec, P(names), xarr_spec,
-                                     {"l0": P(names)}, P(names)),
-                           out_specs=P(names), check_rep=False)
+        sm_fwd = jax.shard_map(_device_fwd_fresh, mesh=mesh,
+                               in_specs=(P(), caches_spec, P(names),
+                                         xarr_spec, {"l0": P(names)},
+                                         P(names)),
+                               out_specs=P(names), check_vma=False)
     else:
         def _device_fwd_fresh(params, caches, dsh, xr):
             logits, _ = _device_forward(params, caches, dsh, xr, xr, False)
             return logits[None]
 
-        sm_fwd = shard_map(_device_fwd_fresh, mesh=mesh,
-                           in_specs=(P(), caches_spec, P(names), xarr_spec),
-                           out_specs=P(names), check_rep=False)
-    caches0 = init_caches(cfg, xplan, p, features=features)
+        sm_fwd = jax.shard_map(_device_fwd_fresh, mesh=mesh,
+                               in_specs=(P(), caches_spec, P(names),
+                                         xarr_spec),
+                               out_specs=P(names), check_vma=False)
+    caches0 = jax.device_put(init_caches(cfg, xplan, p, features=features),
+                             caches_sh)
 
     jit_steps = {"refresh": _make_step(False, True),
                  "cached": _make_step(True, False),
                  "pipelined": _make_step(True, True, defer_refresh=p2p)}
     if host_mode:
         jit_steps["forward"] = jax.jit(
-            lambda params, hd, l0loc, xa: sm_fwd(params, caches0, data_sh,
-                                                 xa, hd, l0loc))
+            lambda params, hd, l0loc, xa, dsh, c0: sm_fwd(params, c0, dsh,
+                                                          xa, hd, l0loc))
     else:
         jit_steps["forward"] = jax.jit(
-            lambda params, xa: sm_fwd(params, caches0, data_sh, xa))
-    state = {"xarr": spmd_exchange_arrays(xplan, p2p=p2p,
+            lambda params, xa, dsh, c0: sm_fwd(params, c0, dsh, xa))
+    state = {"xarr": spmd_exchange_arrays(xplan, p2p, mesh, names,
                                           include_host=host_mode),
              "tracer": NULL_TRACER}
 
@@ -677,7 +689,8 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
         def stepper(params, opt_state, caches):
             xa = state["xarr"]
             with host_annotation(ann):
-                return jit_steps[name](params, opt_state, caches, xa, xa)
+                return jit_steps[name](params, opt_state, caches, xa, xa,
+                                       data_sh)
         return stepper
 
     if host_mode:
@@ -781,7 +794,7 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
                 xa = state["xarr"]
                 with host_annotation(ann):
                     out = jit_steps[name](params, opt_state, caches, hostd,
-                                          state["l0loc"], xa, xa)
+                                          state["l0loc"], xa, xa, data_sh)
                 if emit:
                     new_p, new_s, out_caches, host_out, metrics = out
                     with tr.span("writeback"):
@@ -794,7 +807,7 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
 
         def _set_plan(xp: ExchangePlan):
             tr = state["tracer"]
-            state["xarr"] = spmd_exchange_arrays(xp, p2p=p2p,
+            state["xarr"] = spmd_exchange_arrays(xp, p2p, mesh, names,
                                                  include_host=True)
             state["hostnp"] = _host_np(xp)
             state["l0_ring"].clear()     # flushed, never accounted
@@ -809,11 +822,12 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
             with tr.span("l0_stage"):
                 hostd = {"l0": _take_l0(), "gl": _take_gl()}
             xr = state["xarr"]
-            xe = spmd_exchange_arrays(new_xp, p2p=p2p, include_host=True)
+            xe = spmd_exchange_arrays(new_xp, p2p, mesh, names,
+                                      include_host=True)
             with host_annotation("capgnn/step_transition"):
                 new_p, new_s, out_caches, host_out, metrics = (
                     jit_steps["pipelined"](params, opt_state, caches, hostd,
-                                           state["l0loc"], xr, xe))
+                                           state["l0loc"], xr, xe, data_sh))
             state["xarr"] = xe
             state["hostnp"] = _host_np(new_xp)
             with tr.span("writeback"):
@@ -839,13 +853,15 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
             sf = _stage_l0()
             store.account_fetch(sf)
             return jit_steps["forward"](params, {"l0": sf.array},
-                                        state["l0loc"], state["xarr"])
+                                        state["l0loc"], state["xarr"],
+                                        data_sh, caches0)
 
         step_wrap = wrap_host
         _prefetch_l0()
     else:
         def forward_fresh(params):
-            return jit_steps["forward"](params, state["xarr"])
+            return jit_steps["forward"](params, state["xarr"], data_sh,
+                                        caches0)
 
         step_wrap = wrap
 
@@ -875,4 +891,4 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
                        transport=transport, halo_dtype_bytes=hd_bytes,
                        features=features, host_store=store,
                        jit_steps=jit_steps, _state=state, stacked=sp,
-                       spec=spec)
+                       spec=spec, data=data_sh)

@@ -84,7 +84,6 @@ class TrainSpec:
     halo_dtype: str = "f32"         # wire payload dtype (f32 | bf16)
     exchange_layer0: bool = True
     donate: bool = True
-    interpret: bool = True          # Pallas interpret mode (CPU CI)
     pallas_pack: bool = False
     prefetch_depth: int = 2         # host-store double-buffer depth
     # staleness / caching schedule (halo_1d)
@@ -155,7 +154,6 @@ class TrainSpec:
             halo_dtype=get("halo_dtype", "f32"),
             exchange_layer0=not get("jaca", True),
             donate=get("donate", True),
-            interpret=get("interpret", True),
             pallas_pack=get("pallas_pack", False),
             prefetch_depth=int(get("prefetch_depth", 2)),
             pipeline=bool(get("pipeline", False)),

@@ -49,7 +49,7 @@ oracle's exact mean-loss gradient (pinned to 1e-5 by
 
 Byte accounting.  ``forward_collective_bytes_per_device`` models the
 result-shape bytes of exactly the collectives above, matching
-:func:`repro.launch.dryrun.collective_bytes` over the lowered forward
+:func:`repro.launch.hlo_cost.collective_bytes` over the lowered forward
 HLO op-for-op (gated in ``benchmarks/comm_volume.py``).
 """
 from __future__ import annotations
@@ -163,7 +163,7 @@ def build_spmm15d_layout(ps, task, spec) -> Spmm15dLayout:
 def forward_collective_bytes_per_device(layout: Spmm15dLayout, cfg,
                                         spec) -> int:
     """Modeled per-device result-shape bytes of the forward collectives —
-    the quantity :func:`repro.launch.dryrun.collective_bytes` measures on
+    the quantity :func:`repro.launch.hlo_cost.collective_bytes` measures on
     the lowered forward HLO: per layer one ``collective-permute``
     (``[ni, d]``, wire dtype; c > 1), one ``all-gather`` (``[g*ni, d]``,
     wire dtype; g > 1) and one ``all-reduce`` (``[ni, d]``, f32; c > 1).
@@ -251,12 +251,7 @@ def make_spmm15d_runtime(cfg, layout: Spmm15dLayout, opt, spec,
     (``spec.donate``) so steady-state steps update in place."""
     import jax
     import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    if hasattr(jax, "shard_map"):
-        shard_map = jax.shard_map
-    else:                              # pre-jax.shard_map releases
-        from jax.experimental.shard_map import shard_map
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.models.gnn import accuracy, cross_entropy_loss
     from .capgnn_sim import halo_dtype_info
@@ -301,7 +296,9 @@ def make_spmm15d_runtime(cfg, layout: Spmm15dLayout, opt, spec,
                 blocks = sp.feats[j * g:(j + 1) * g].reshape(g * ni, f)
                 hg0[i * c + j] = blocks
         data["hg0"] = hg0
-    data = jax.tree.map(jnp.asarray, data)
+    # placed once, one block per device: no step call reshards them
+    data = jax.device_put(jax.tree.map(jnp.asarray, data),
+                          NamedSharding(mesh, P(AXES_15D)))
 
     total_train = float(np.maximum(sp.train_mask.sum(), 1.0))
     swap = [(a * c + j, j * c + a) for a in range(c) for j in range(c)]
@@ -363,13 +360,14 @@ def make_spmm15d_runtime(cfg, layout: Spmm15dLayout, opt, spec,
         return new_params, new_state, {"loss": loss, "acc": acc}
 
     names3 = AXES_15D
-    sm_step = shard_map(_device_step, mesh=mesh,
-                        in_specs=(P(), P(), P(names3)),
-                        out_specs=(P(), P(), {"loss": P(), "acc": P()}),
-                        check_rep=False)
-    sm_fwd = shard_map(lambda params, dsh: _device_forward(params, dsh)[None],
-                       mesh=mesh, in_specs=(P(), P(names3)),
-                       out_specs=P(names3), check_rep=False)
+    sm_step = jax.shard_map(_device_step, mesh=mesh,
+                            in_specs=(P(), P(), P(names3)),
+                            out_specs=(P(), P(), {"loss": P(), "acc": P()}),
+                            check_vma=False)
+    sm_fwd = jax.shard_map(
+        lambda params, dsh: _device_forward(params, dsh)[None],
+        mesh=mesh, in_specs=(P(), P(names3)), out_specs=P(names3),
+        check_vma=False)
     jit_step = jax.jit(lambda params, opt_state, dsh:
                        sm_step(params, opt_state, dsh),
                        donate_argnums=(0, 1) if spec.donate else ())

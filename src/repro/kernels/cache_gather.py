@@ -1,10 +1,12 @@
 """Pallas TPU kernel: cache row gather (JACA 'pick_cache' hot path).
 
-Gathers cached halo rows ``out[i] = src[idx[i]]`` — the inner loop of the
-cache read path.  Thanks to the reordering pass (repro.graph.reorder) the
-hot cache tier is *contiguous by construction*, so the common case is a
-dense ``dynamic_slice``; this kernel covers the general (permuted) case
-with a tiled vectorised take, VMEM-resident source stripes.
+Gathers cached rows ``out[i] = src[idx[i]]`` — the inner loop of the
+cache read path (the serve hot tier, the p2p peer pack).  A pure DMA
+kernel: ``src`` and ``out`` stay in HBM, the ids of one ``block_rows``
+tile sit in SMEM, and each output row is one HBM->HBM copy; a tile's
+copies are all in flight at once and waited together.  Rows are addressed
+as ``[n, 1, d]`` so a single-row copy is tile-aligned; ``d`` must be a
+multiple of 128 lanes (:func:`repro.kernels.ops.gather_rows` pads).
 """
 from __future__ import annotations
 
@@ -13,36 +15,52 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import resolve_interpret
 
 __all__ = ["gather_rows_pallas"]
 
 
-def _kernel(idx_ref, src_ref, out_ref):
-    idx = idx_ref[...]            # [BR, 1] int32
-    src = src_ref[...]            # [n_src, BF]
-    out_ref[...] = jnp.take(src, idx[:, 0], axis=0)
+def _kernel(idx_ref, src_hbm, out_hbm, sem):
+    base = pl.program_id(0) * idx_ref.shape[0]
+
+    def copy(r):
+        return pltpu.make_async_copy(src_hbm.at[idx_ref[r, 0]],
+                                     out_hbm.at[base + r], sem)
+
+    def start(r, c):
+        copy(r).start()
+        return c
+
+    def wait(r, c):
+        copy(r).wait()
+        return c
+
+    jax.lax.fori_loop(0, idx_ref.shape[0], start, 0)
+    jax.lax.fori_loop(0, idx_ref.shape[0], wait, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("block_rows", "block_feat",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def gather_rows_pallas(src: jnp.ndarray, idx: jnp.ndarray, *,
-                       block_rows: int = 128, block_feat: int = 128,
-                       interpret: bool = True) -> jnp.ndarray:
-    """out[i] = src[idx[i]].  idx [n_out] int32, src [n_src, d]."""
+                       block_rows: int = 128,
+                       interpret: bool | None = None) -> jnp.ndarray:
+    """out[i] = src[idx[i]].  idx [n_out] int32 (n_out % block_rows == 0),
+    src [n_src, d] (d % 128 == 0).  ``interpret=None`` follows the
+    platform."""
     n_out = idx.shape[0]
     n_src, d = src.shape
     assert n_out % block_rows == 0, (n_out, block_rows)
-    assert d % block_feat == 0, (d, block_feat)
-    idx2 = idx.reshape(n_out, 1).astype(jnp.int32)
-    grid = (n_out // block_rows, d // block_feat)
-    return pl.pallas_call(
+    assert d % 128 == 0, d
+    out = pl.pallas_call(
         _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((n_src, block_feat), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((block_rows, block_feat), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n_out, d), src.dtype),
-        interpret=interpret,
-    )(idx2, src)
+        grid=(n_out // block_rows,),
+        in_specs=[pl.BlockSpec((block_rows, 1), lambda i: (i, 0),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct((n_out, 1, d), src.dtype),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+        interpret=resolve_interpret(interpret),
+    )(idx.astype(jnp.int32).reshape(n_out, 1), src.reshape(n_src, 1, d))
+    return out.reshape(n_out, d)

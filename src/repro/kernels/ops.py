@@ -11,6 +11,8 @@ from .ell_spmm import ell_spmm_pallas
 from .cache_gather import gather_rows_pallas
 from . import ref as _ref
 
+_LANES = 128    # TPU lane width: the kernels DMA whole 128-lane rows
+
 __all__ = ["ell_pack", "ell_pack_hybrid", "hybrid_spmm", "ell_stats",
            "ell_spmm", "gather_rows", "pack_rows", "cache_combine"]
 
@@ -66,10 +68,10 @@ def ell_pack_hybrid(src: np.ndarray, dst: np.ndarray, w: np.ndarray,
 
 
 def hybrid_spmm(cols: jnp.ndarray, vals: jnp.ndarray, tail_src: jnp.ndarray,
-                tail_dst: jnp.ndarray, tail_w: jnp.ndarray, h: jnp.ndarray,
-                *, interpret: bool = True) -> jnp.ndarray:
+                tail_dst: jnp.ndarray, tail_w: jnp.ndarray, h: jnp.ndarray
+                ) -> jnp.ndarray:
     """ELL kernel over the regular part + segment-sum over the COO tail."""
-    out = ell_spmm(cols, vals, h, interpret=interpret)
+    out = ell_spmm(cols, vals, h)
     if tail_src.shape[0]:
         msgs = h[tail_src] * tail_w[:, None].astype(h.dtype)
         out = out + jax.ops.segment_sum(msgs, tail_dst,
@@ -97,50 +99,48 @@ def _pad_to(x: jnp.ndarray, mult: int, axis: int) -> jnp.ndarray:
 
 
 def ell_spmm(cols: jnp.ndarray, vals: jnp.ndarray, h: jnp.ndarray, *,
-             block_rows: int = 128, block_feat: int = 128,
-             col_chunk: int | None = None,
-             interpret: bool = True) -> jnp.ndarray:
-    """Padded/dispatched ELL SpMM; returns [n_rows, d] (unpadded)."""
+             block_rows: int = 32, interpret: bool | None = None
+             ) -> jnp.ndarray:
+    """Padded ELL SpMM; returns [n_rows, d] (unpadded).  Rows are padded
+    to ``block_rows`` and features to the 128-lane tile the kernel DMAs."""
     n_rows = cols.shape[0]
     d = h.shape[1]
     cols_p = _pad_to(cols, block_rows, 0)
     vals_p = _pad_to(vals, block_rows, 0)
-    h_p = _pad_to(h, block_feat, 1)
+    h_p = _pad_to(h, _LANES, 1)
     out = ell_spmm_pallas(cols_p, vals_p, h_p, block_rows=block_rows,
-                          block_feat=block_feat, col_chunk=col_chunk,
                           interpret=interpret)
     return out[:n_rows, :d]
 
 
 def gather_rows(src: jnp.ndarray, idx: jnp.ndarray, *,
-                block_rows: int = 128, block_feat: int = 128,
-                interpret: bool = True) -> jnp.ndarray:
+                block_rows: int = 128, interpret: bool | None = None
+                ) -> jnp.ndarray:
+    """Padded row gather ``src[idx]``; returns [n_out, d] (unpadded)."""
     if idx.shape[0] == 0:
         return jnp.zeros((0, src.shape[1]), src.dtype)
     n_out, d = idx.shape[0], src.shape[1]
     idx_p = _pad_to(idx, block_rows, 0)
-    src_p = _pad_to(src, block_feat, 1)
+    src_p = _pad_to(src, _LANES, 1)
     out = gather_rows_pallas(src_p, idx_p, block_rows=block_rows,
-                             block_feat=block_feat, interpret=interpret)
+                             interpret=interpret)
     return out[:n_out, :d]
 
 
 def pack_rows(src: jnp.ndarray, idx: jnp.ndarray, *,
-              use_pallas: bool = False, interpret: bool = True
-              ) -> jnp.ndarray:
+              use_pallas: bool = False) -> jnp.ndarray:
     """Fused peer-pack gather: pull ``src`` rows for an arbitrarily-shaped
     index block in one pass, e.g. the ``[P, B]`` per-peer send layout of
     the p2p halo transport -> ``[P, B, d]`` payload.
 
     ``use_pallas=True`` routes the flattened gather through the Pallas
-    :func:`gather_rows` kernel (one VMEM sweep over ``src`` per block tile
-    — the TPU path); the default is a plain ``take``, which XLA fuses into
-    the surrounding send-buffer pack and is faster under CPU interpret
-    mode.  Both produce identical rows.
+    :func:`gather_rows` kernel (one DMA per row); the default is a plain
+    ``take``, which XLA fuses into the surrounding send-buffer pack.  Both
+    produce identical rows.
     """
     flat = idx.reshape(-1)
     if use_pallas:
-        out = gather_rows(src, flat, interpret=interpret)
+        out = gather_rows(src, flat)
     else:
         out = jnp.take(src, flat, axis=0)
     return out.reshape(*idx.shape, src.shape[1])

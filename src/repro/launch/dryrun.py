@@ -21,7 +21,6 @@ device count at first init); do not move it.
 import argparse
 import dataclasses
 import json
-import re
 import time
 
 import jax
@@ -35,54 +34,9 @@ from repro.models.transformer import (ModelConfig, use_spmd, loss_fn,
 from repro.optim import adam
 from repro.launch.mesh import make_production_mesh, dp_axes, HW
 from repro.launch import sharding as shd
+from repro.launch.hlo_cost import collective_bytes
 
 __all__ = ["run_one", "collective_bytes", "main"]
-
-_DTYPE_BYTES = {"f64": 8, "f32": 4, "f16": 2, "bf16": 2, "s64": 8, "u64": 8,
-                "s32": 4, "u32": 4, "s16": 2, "u16": 2, "s8": 1, "u8": 1,
-                "pred": 1, "f8e4m3fn": 1, "f8e5m2": 1}
-
-_COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
-                "collective-permute")
-
-
-def _shape_bytes(type_str: str) -> int:
-    """Bytes of an HLO result type, e.g. 'bf16[8,128]' or a tuple of them."""
-    total = 0
-    for m in re.finditer(r"(\w+)\[([\d,]*)\]", type_str):
-        dt, dims = m.group(1), m.group(2)
-        if dt not in _DTYPE_BYTES:
-            continue
-        n = 1
-        if dims:
-            for d in dims.split(","):
-                n *= int(d)
-        total += n * _DTYPE_BYTES[dt]
-    return total
-
-
-def collective_bytes(hlo_text: str) -> dict:
-    """Sum result bytes of every collective op in compiled HLO (per device),
-    bucketed by collective kind."""
-    out = {k: 0 for k in _COLLECTIVES}
-    counts = {k: 0 for k in _COLLECTIVES}
-    for line in hlo_text.splitlines():
-        line = line.strip()
-        m = re.match(r"^(\S+)\s*=\s*((?:\([^)]*\))|(?:\S+))\s+(\S+)\(", line)
-        if not m:
-            continue
-        op = m.group(3)
-        base = op.split(".")[0]
-        # match e.g. all-gather, all-gather-start, all-reduce-start
-        for kind in _COLLECTIVES:
-            if base == kind or base.startswith(kind + "-"):
-                if base.endswith("-done"):
-                    break
-                out[kind] += _shape_bytes(m.group(2))
-                counts[kind] += 1
-                break
-    out_total = sum(out.values())
-    return {"per_kind": out, "counts": counts, "total": out_total}
 
 
 def build_step(cfg: ModelConfig, shape_name: str, mesh,

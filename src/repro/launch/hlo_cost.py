@@ -33,42 +33,7 @@ import dataclasses
 import math
 import re
 
-__all__ = ["analyse_hlo", "HloCost", "xla_cost_analysis"]
-
-# --- version-compat shims -------------------------------------------------
-# `jax.shard_map` graduated from `jax.experimental.shard_map` in newer
-# releases; callers (tests, benchmarks) use the top-level name, so backfill
-# it on older installs.
-try:
-    import functools as _functools
-
-    import jax as _jax
-    if not hasattr(_jax, "shard_map"):
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        @_functools.wraps(_shard_map)
-        def _shard_map_compat(*args, **kwargs):
-            # The experimental version's replication checker predates the
-            # scan-carry fix (it rejects psum-in-scan bodies); the graduated
-            # API does not have that failure mode, so default the check off.
-            kwargs.setdefault("check_rep", False)
-            return _shard_map(*args, **kwargs)
-
-        _jax.shard_map = _shard_map_compat
-except ImportError:          # HLO text analysis itself needs no jax
-    pass
-
-
-def xla_cost_analysis(compiled) -> dict:
-    """XLA's own per-module cost analysis as a plain dict.
-
-    ``Compiled.cost_analysis()`` returned a one-element list of dicts before
-    jax 0.5 and a bare dict after; normalise so callers can index by key.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+__all__ = ["analyse_hlo", "HloCost", "collective_bytes"]
 
 _DTYPE_BYTES = {"f64": 8, "f32": 4, "f16": 2, "bf16": 2, "s64": 8, "u64": 8,
                 "s32": 4, "u32": 4, "s16": 2, "u16": 2, "s8": 1, "u8": 1,
@@ -218,6 +183,30 @@ def _type_bytes(type_str: str) -> int:
                 n *= int(d)
         total += n * _DTYPE_BYTES[dt]
     return total
+
+
+def collective_bytes(hlo_text: str) -> dict:
+    """Sum result bytes of every collective op in compiled HLO (per device),
+    bucketed by collective kind."""
+    out = {k: 0 for k in _COLLECTIVES}
+    counts = {k: 0 for k in _COLLECTIVES}
+    for line in hlo_text.splitlines():
+        line = line.strip()
+        m = re.match(r"^(\S+)\s*=\s*((?:\([^)]*\))|(?:\S+))\s+(\S+)\(", line)
+        if not m:
+            continue
+        op = m.group(3)
+        base = op.split(".")[0]
+        # match e.g. all-gather, all-gather-start, all-reduce-start
+        for kind in _COLLECTIVES:
+            if base == kind or base.startswith(kind + "-"):
+                if base.endswith("-done"):
+                    break
+                out[kind] += _type_bytes(m.group(2))
+                counts[kind] += 1
+                break
+    out_total = sum(out.values())
+    return {"per_kind": out, "counts": counts, "total": out_total}
 
 
 def _shape_dims(type_str: str) -> list[int]:

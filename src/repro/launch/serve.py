@@ -57,7 +57,9 @@ def run_lm(args) -> dict:
     return out
 
 
-def run_gnn(args) -> dict:
+def run_gnn(args):
+    """Precompute, build the engine and serve the stream; returns the
+    printed report and the engine (for callers that query it further)."""
     import jax
     from repro.core import (PROFILES, PAPER_GROUPS, make_group, cal_capacity,
                             build_cache_plan)
@@ -167,10 +169,11 @@ def run_gnn(args) -> dict:
         out["trace_file"] = paths["trace"]
         out["metrics_file"] = paths["metrics"]
     print(json.dumps(out, indent=1))
-    return out
+    return out, engine
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
+    """The ``gnn`` / ``lm`` command line (``main`` parses ``sys.argv``)."""
     ap = argparse.ArgumentParser()
     sub = ap.add_subparsers(dest="cmd", required=True)
 
@@ -239,8 +242,13 @@ def main():
     l.add_argument("--cache-len", type=int, default=256)
     l.add_argument("--seed", type=int, default=0)
     l.set_defaults(fn=run_lm)
+    return ap
 
-    args = ap.parse_args()
+
+def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    args = build_parser().parse_args()
     args.fn(args)
 
 

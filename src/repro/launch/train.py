@@ -8,8 +8,10 @@ Two entry points:
 - ``python -m repro.launch.train lm --arch <id> ...`` — token-LM training
   for the architecture-zoo configs (reduced or full), single host.
 
-Both are host-scale drivers; the production mesh path is exercised by
-``repro.launch.dryrun`` (this container has one real device).
+``gnn`` runs the partitions stacked on one device (``halo_1d``) or over a
+device mesh (``--strategy spmm_15d``); ``chip_smoke.py`` drives it at
+Flickr's published shape on a TPU.  The LM mesh path is exercised by
+``repro.launch.dryrun``.
 """
 from __future__ import annotations
 
@@ -171,6 +173,7 @@ def run_gnn(args) -> dict:
         "stack_waste_frac": runtime.padding_stats().get("waste_frac"),
         "epochs": args.epochs, "resumed_from": start_epoch,
         "final_loss": report.losses[-1] if report.losses else None,
+        "losses": report.losses,
         "halo_dtype": halo_dtype,
         "features": features, "prefetch_depth": prefetch_depth,
         "host_fetch_rows": report.host_fetch_rows,
@@ -252,6 +255,7 @@ def _run_gnn_15d(args, spec, strat, task, ps, cfg, group, uneven) -> dict:
         "inner_sizes": [pt.n_inner for pt in ps.parts],
         "epochs": args.epochs, "resumed_from": start_epoch,
         "final_loss": report.losses[-1] if report.losses else None,
+        "losses": report.losses,
         "halo_dtype": spec.halo_dtype,
         "test_acc": test_acc, "comm_bytes": report.comm_bytes,
         # vanilla = dense 1D full-H all-gather on the same block rows, so
@@ -324,7 +328,8 @@ def run_lm(args) -> dict:
     return out
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
+    """The ``gnn`` / ``lm`` command line (``main`` parses ``sys.argv``)."""
     ap = argparse.ArgumentParser()
     sub = ap.add_subparsers(dest="cmd", required=True)
 
@@ -454,8 +459,13 @@ def main():
                         "checkpoint in --ckpt-dir and run the remaining "
                         "steps up to --steps")
     l.set_defaults(fn=run_lm)
+    return ap
 
-    args = ap.parse_args()
+
+def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    args = build_parser().parse_args()
     args.fn(args)
 
 

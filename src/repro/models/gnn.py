@@ -118,7 +118,6 @@ class EllAdj(Adjacency):
     cols: jnp.ndarray     # [n_rows, max_deg] local col ids (padded)
     vals: jnp.ndarray     # [n_rows, max_deg] weights (0 at padding)
     n_cols_: int
-    interpret: bool = True
 
     @property
     def n_rows(self):
@@ -130,7 +129,7 @@ class EllAdj(Adjacency):
 
     def spmm(self, h):
         from repro.kernels.ops import ell_spmm
-        return ell_spmm(self.cols, self.vals, h, interpret=self.interpret)
+        return ell_spmm(self.cols, self.vals, h)
 
     def spmm_at(self, e_vals, h):
         """SpMM with ELL-shaped per-edge values ``[n_rows, max_deg]``.
@@ -140,7 +139,7 @@ class EllAdj(Adjacency):
         """
         from repro.kernels.ops import ell_spmm
         v = jnp.where(self.vals != 0, e_vals, 0.0)
-        return ell_spmm(self.cols, v, h, interpret=self.interpret)
+        return ell_spmm(self.cols, v, h)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -158,7 +157,6 @@ class HybridAdj(Adjacency):
     tail_dst: jnp.ndarray  # [mt] inner row ids (n_rows = padding)
     tail_w: jnp.ndarray    # [mt] weights (0 at padding)
     n_cols_: int
-    interpret: bool = True
 
     @property
     def n_rows(self):
@@ -171,12 +169,11 @@ class HybridAdj(Adjacency):
     def spmm(self, h):
         from repro.kernels.ops import hybrid_spmm
         return hybrid_spmm(self.cols, self.vals, self.tail_src,
-                           self.tail_dst, self.tail_w, h,
-                           interpret=self.interpret)
+                           self.tail_dst, self.tail_w, h)
 
 
-def make_local_adj(local_graph: Graph, n_inner: int, backend: str = "edges",
-                   interpret: bool = True) -> Adjacency:
+def make_local_adj(local_graph: Graph, n_inner: int, backend: str = "edges"
+                   ) -> Adjacency:
     """Build an Adjacency for a partition-local graph (rows = inner)."""
     src, dst = local_graph.edges()
     keep = dst < n_inner
@@ -194,14 +191,13 @@ def make_local_adj(local_graph: Graph, n_inner: int, backend: str = "edges",
     if backend == "ell":
         from repro.kernels.ops import ell_pack
         cols, vals = ell_pack(src, dst, w, n_inner)
-        return EllAdj(jnp.asarray(cols), jnp.asarray(vals), n_cols,
-                      interpret=interpret)
+        return EllAdj(jnp.asarray(cols), jnp.asarray(vals), n_cols)
     if backend == "hybrid":
         from repro.kernels.ops import ell_pack_hybrid
         cols, vals, ts, td, tw = ell_pack_hybrid(src, dst, w, n_inner)
         return HybridAdj(jnp.asarray(cols), jnp.asarray(vals),
                          jnp.asarray(ts), jnp.asarray(td), jnp.asarray(tw),
-                         n_cols, interpret=interpret)
+                         n_cols)
     raise ValueError(f"unknown aggregation backend {backend!r}; "
                      f"expected one of {BACKENDS}")
 

@@ -26,15 +26,14 @@ after summing into report totals:
 """
 from .tracer import (NULL_TRACER, SPAN_KINDS, STEP_KINDS, Span,
                      StepCounters, Tracer, device_peak_bytes)
-from .annotations import (annotate_function, device_scope, device_trace,
-                          host_annotation)
+from .annotations import device_scope, device_trace, host_annotation
 from .export import (chrome_trace_events, validate_chrome_trace,
                      write_chrome_trace, write_metrics_jsonl)
 
 __all__ = [
     "Tracer", "Span", "StepCounters", "NULL_TRACER",
     "STEP_KINDS", "SPAN_KINDS", "device_peak_bytes",
-    "device_scope", "host_annotation", "annotate_function", "device_trace",
+    "device_scope", "host_annotation", "device_trace",
     "chrome_trace_events", "write_chrome_trace", "write_metrics_jsonl",
     "validate_chrome_trace",
 ]

@@ -1,49 +1,29 @@
 """Device-side visibility helpers: ``jax.named_scope`` inside jitted
 code, ``jax.profiler.TraceAnnotation`` around host dispatch sites, and an
 opt-in ``jax.profiler.trace`` capture directory.
-
-Everything degrades to a no-op when the corresponding jax API is missing,
-so the runtimes never gate on profiler availability.
 """
 from __future__ import annotations
 
 import contextlib
 
-__all__ = ["device_scope", "host_annotation", "annotate_function",
-           "device_trace"]
+import jax
+from jax.profiler import TraceAnnotation
 
-_NULL = contextlib.nullcontext()
+__all__ = ["device_scope", "host_annotation", "device_trace"]
 
 
 def device_scope(name: str):
     """Name a region *inside* jitted/traced code: the scope lands in the
     HLO op metadata, so XLA profiles attribute kernels (layer loop, tier
     pulls, refresh rings, Pallas SpMM) to it."""
-    try:
-        import jax
-        return jax.named_scope(name)
-    except Exception:
-        return _NULL
+    return jax.named_scope(name)
 
 
 def host_annotation(name: str):
     """Annotate a host-side dispatch site (step call, h2d staging) so an
     active ``jax.profiler`` capture shows it on the host track.  A cheap
-    TraceMe when no capture is running; nullcontext if unavailable."""
-    try:
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation(name)
-    except Exception:
-        return _NULL
-
-
-def annotate_function(fn, name: str | None = None):
-    """``jax.profiler.annotate_function`` with a graceful fallback."""
-    try:
-        from jax.profiler import annotate_function as _af
-        return _af(fn, name=name)
-    except Exception:
-        return fn
+    TraceMe when no capture is running."""
+    return TraceAnnotation(name)
 
 
 @contextlib.contextmanager
@@ -54,6 +34,5 @@ def device_trace(trace_dir: str | None):
     if not trace_dir:
         yield
         return
-    import jax
     with jax.profiler.trace(trace_dir):
         yield

@@ -19,7 +19,8 @@ import dataclasses
 import time
 
 __all__ = ["Tracer", "Span", "StepCounters", "NULL_TRACER",
-           "STEP_KINDS", "SPAN_KINDS", "device_peak_bytes"]
+           "STEP_KINDS", "SPAN_KINDS", "device_memory_stats",
+           "device_peak_bytes"]
 
 # top-level step flavours of the training loop (depth-0 spans)
 STEP_KINDS = ("refresh", "cached", "pipelined", "transition")
@@ -32,18 +33,28 @@ SPAN_KINDS = STEP_KINDS + ("replan", "h2d_prefetch", "l0_stage",
                            "fetch_retry", "mem_backoff")
 
 
+def device_memory_stats() -> dict | None:
+    """``memory_stats()`` of the first local device.  ``None`` on the host
+    CPU backend, which reports none; an accelerator that reports none is
+    an error, not a silent default."""
+    import jax
+    dev = jax.local_devices()[0]
+    st = dev.memory_stats()
+    if st:
+        return st
+    if dev.platform == "cpu":
+        return None
+    raise RuntimeError(f"{dev.platform} device {dev.device_kind!r} "
+                       "reports no memory_stats()")
+
+
 def device_peak_bytes() -> int | None:
-    """Peak device memory in use, from ``Device.memory_stats()``; ``None``
-    where the backend does not report it (host CPU devices)."""
-    try:
-        import jax
-        st = jax.local_devices()[0].memory_stats()
-    except Exception:
+    """Peak device memory in use, from :func:`device_memory_stats`;
+    ``None`` on the host CPU backend."""
+    st = device_memory_stats()
+    if st is None:
         return None
-    if not st:
-        return None
-    v = st.get("peak_bytes_in_use", st.get("bytes_in_use"))
-    return int(v) if v is not None else None
+    return int(st["peak_bytes_in_use"])
 
 
 @dataclasses.dataclass

@@ -187,13 +187,12 @@ class GNNServeEngine:
 
     def __init__(self, store: EmbeddingStore, params, graph: Graph,
                  hot_ids: np.ndarray, features: np.ndarray | None = None,
-                 fresh_hops: int | None = None, interpret: bool = True,
+                 fresh_hops: int | None = None,
                  host_store: HostFeatureStore | None = None):
         self.store = store
         self.cfg = store.cfg
         self.params = params
         self.graph = graph
-        self.interpret = interpret
         self.fresh_hops = (self.cfg.num_layers if fresh_hops is None
                            else int(fresh_hops))
         n = store.num_nodes
@@ -321,8 +320,7 @@ class GNNServeEngine:
         hit = slots >= 0
         if hit.any():
             with self.tracer.span("hot_gather", rows=int(hit.sum())):
-                rows = gather_rows(self.hot_buf, jnp.asarray(slots[hit]),
-                                   interpret=self.interpret)
+                rows = gather_rows(self.hot_buf, jnp.asarray(slots[hit]))
                 out[hit] = np.asarray(rows)
         if (~hit).any():
             t0 = time.perf_counter()

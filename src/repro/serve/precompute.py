@@ -28,7 +28,7 @@ import numpy as np
 from repro.checkpoint import latest_step, load_checkpoint, save_checkpoint
 from repro.dist.capgnn_sim import (_build_global, _glob_dict, _pull,
                                    _read_global, _scatter, _tier_dict,
-                                   make_adj_builder)
+                                   make_adj_builder, stacked_rows)
 from repro.dist.exchange import ExchangePlan, StackedParts
 from repro.graph.partition import PartitionSet
 from repro.models.gnn import GNNConfig, _layer_apply
@@ -65,12 +65,11 @@ class EmbeddingStore:
 
 def precompute_embeddings(cfg: GNNConfig, ps: PartitionSet, sp: StackedParts,
                           xplan: ExchangePlan, params,
-                          backend: str = "edges",
-                          interpret: bool = True) -> EmbeddingStore:
+                          backend: str = "edges") -> EmbeddingStore:
     """One fresh partition-parallel forward pass, keeping every layer.
 
     Numerically identical to ``SimRuntime.forward_fresh`` (same tier pulls,
-    same vmapped per-partition layer apply, same backend packs), so the
+    same block-diagonal stacked layer apply, same backend packs), so the
     final table equals the training runtime's fresh logits — asserted by
     the serving parity tests.
     """
@@ -78,20 +77,20 @@ def precompute_embeddings(cfg: GNNConfig, ps: PartitionSet, sp: StackedParts,
     layers = cfg.num_layers
     feats = jnp.asarray(sp.feats)
     halo_feats = jnp.asarray(sp.halo_feats)
-    adj_leaves, build_adj = make_adj_builder(sp, backend, interpret)
+    adj_leaves, build_adj = make_adj_builder(sp, backend, stacked=True)
     un_d = _tier_dict(xplan.uncached)
     loc_d = _tier_dict(xplan.local)
     glob_d = _glob_dict(xplan.glob)
 
-    def layer_all(lp, h, halo, is_last):
-        def one(lv, hi, hhi):
-            adj = build_adj(lv)
-            h_local = jnp.concatenate([hi, hhi], axis=0)
-            return _layer_apply(cfg, lp, adj, h_local, ni, is_last)
-        return jax.vmap(one)(adj_leaves, h, halo)
+    def layer_all(lp, h, halo, is_last, adj_lv):
+        out = _layer_apply(cfg, lp, build_adj(adj_lv), stacked_rows(h, halo),
+                           p * ni, is_last)
+        return out.reshape(p, ni, -1)
 
+    # the stacked inputs and tier programs are arguments: captured, they
+    # would be baked into the executable as constants
     @jax.jit
-    def run(params):
+    def run(params, feats, halo_feats, adj_lv, un_d, loc_d, glob_d):
         h = feats
         outs = [h]
         for li, lp in enumerate(params):
@@ -105,11 +104,12 @@ def precompute_embeddings(cfg: GNNConfig, ps: PartitionSet, sp: StackedParts,
                 halo = _scatter(halo, loc_d["recv_halo_pos"], _pull(loc_d, h),
                                 loc_d["recv_valid"])
                 halo = _read_global(glob_d, _build_global(glob_d, h), halo)
-            h = layer_all(lp, h, halo, is_last=(li == layers - 1))
+            h = layer_all(lp, h, halo, (li == layers - 1), adj_lv)
             outs.append(h)
         return outs
 
-    outs = [np.asarray(o) for o in run(params)]
+    outs = [np.asarray(o) for o in run(params, feats, halo_feats, adj_leaves,
+                                       un_d, loc_d, glob_d)]
     n = ps.graph.num_nodes
     tables = []
     for o in outs:

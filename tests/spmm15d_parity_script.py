@@ -18,7 +18,7 @@ Covers, per ISSUE 10's acceptance criteria:
   replication-cotangent bugs exactly);
 - loss-trajectory parity <= 1e-5 over 6 adam epochs;
 - modeled forward collective bytes == HLO-measured
-  (:func:`repro.launch.dryrun.collective_bytes` over the compiled
+  (:func:`repro.launch.hlo_cost.collective_bytes` over the compiled
   forward), including the ``exchange_layer0=False`` pre-replicated
   variant.
 """
@@ -65,7 +65,7 @@ def check_case(c, pr, exchange_layer0=True):
     from repro.dist.strategy_15d import (build_spmm15d_layout,
                                          make_spmm15d_runtime,
                                          train_spmm15d)
-    from repro.launch.dryrun import collective_bytes
+    from repro.launch.hlo_cost import collective_bytes
     from repro.models.gnn import init_gnn
     from repro.optim import adam, sgd
 

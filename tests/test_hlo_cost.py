@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.launch.hlo_cost import analyse_hlo, xla_cost_analysis
+from repro.launch.hlo_cost import analyse_hlo
 
 
 def _compile(fn, *args):
@@ -26,7 +26,7 @@ def test_scan_flops_counted_per_iteration():
     expect = 12 * 3 * (2 * 8 * 64 * 64)
     assert c.flops == pytest.approx(expect, rel=0.01)
     # XLA's own analysis counts the body once — ours must exceed it
-    assert c.flops > xla_cost_analysis(comp)["flops"] * 5
+    assert c.flops > comp.cost_analysis()["flops"] * 5
     assert c.unresolved_loops == 0
 
 

@@ -44,17 +44,18 @@ def test_ell_spmm_matches_oracle(n_rows, max_deg, n_cols, d, dtype):
                                rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("col_chunk", [64, 128])
-def test_ell_spmm_column_chunked(col_chunk):
-    """Chunked accumulation (VMEM-bounded path) must equal monolithic."""
+@pytest.mark.parametrize("block_k", [64, 128])
+def test_ell_spmm_column_chunked(block_k):
+    """Accumulation over ELL column (neighbour-slot) chunks — the
+    SMEM-bounded path for wide rows — must equal one chunk."""
     rng = np.random.default_rng(7)
-    n_rows, max_deg, n_cols, d = 128, 8, 256, 128
+    n_rows, max_deg, n_cols, d = 64, 300, 256, 128
     cols, vals = _rand_ell(rng, n_rows, max_deg, n_cols, np.float32)
     h = jnp.asarray(rng.normal(size=(n_cols, d)).astype(np.float32))
     mono = ell_spmm_pallas(jnp.asarray(cols), jnp.asarray(vals), h,
-                           interpret=True)
+                           block_k=max_deg, interpret=True)
     chunked = ell_spmm_pallas(jnp.asarray(cols), jnp.asarray(vals), h,
-                              col_chunk=col_chunk, interpret=True)
+                              block_k=block_k, interpret=True)
     np.testing.assert_allclose(np.asarray(chunked), np.asarray(mono),
                                rtol=1e-5, atol=1e-5)
 
