@@ -1,4 +1,4 @@
-"""Benchmark orchestrator: one suite per paper table/figure + roofline.
+"""Benchmark orchestrator: one suite per paper table/figure.
 
     PYTHONPATH=src python -m benchmarks.run [--only name[,name...] ...]
 
@@ -24,7 +24,7 @@ import time
 SUITES = ["halo_obs", "cache_hit", "comm_volume", "rapa_balance",
           "heterogeneous", "convergence", "overall", "kernels_bench",
           "serve_bench", "adaptive_cache", "out_of_core",
-          "fault_tolerance", "roofline"]
+          "fault_tolerance"]
 
 _SUMMARY = "bench_summary"
 # not suite outputs: the folded summary itself and the regression baseline

@@ -45,7 +45,7 @@ from repro.faults.plan import NULL_FAULTS
 from repro.models.gnn import (EdgeListAdj, EllAdj, GNNConfig, HybridAdj,
                               _layer_apply, accuracy, cross_entropy_loss,
                               init_gnn)
-from repro.obs.annotations import device_scope, host_annotation
+from repro.obs.annotations import device_scope
 from repro.obs.tracer import NULL_TRACER, StepCounters, device_peak_bytes
 from repro.optim import Optimizer
 
@@ -495,9 +495,8 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
         data["halo_feats"] = halo_feats
 
     def layer_all(lp, h, halo, is_last, adj_lv):
-        with device_scope("spmm_layer"):
-            out = _layer_apply(cfg, lp, build_adj(adj_lv),
-                               stacked_rows(h, halo), p * ni, is_last)
+        out = _layer_apply(cfg, lp, build_adj(adj_lv),
+                           stacked_rows(h, halo), p * ni, is_last)
         return out.reshape(p, ni, -1)
 
     def forward(params, caches, xr, xe, use_stale: bool, data,
@@ -520,18 +519,20 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
         for li, lp in enumerate(params):
             if li == 0:
                 if host_mode:
-                    halo = jnp.zeros((p, nh, h.shape[-1]), h.dtype)
-                    halo = _scatter(halo, xr["loc"]["recv_halo_pos"],
-                                    l0loc.astype(h.dtype),
-                                    xr["loc"]["recv_valid"])
-                    halo = _scatter(halo, xr["host"]["feat_pos"],
-                                    hostd["l0"].astype(h.dtype),
-                                    xr["host"]["feat_valid"])
+                    with device_scope("cache_read"):
+                        halo = jnp.zeros((p, nh, h.shape[-1]), h.dtype)
+                        halo = _scatter(halo, xr["loc"]["recv_halo_pos"],
+                                        l0loc.astype(h.dtype),
+                                        xr["loc"]["recv_valid"])
+                        halo = _scatter(halo, xr["host"]["feat_pos"],
+                                        hostd["l0"].astype(h.dtype),
+                                        xr["host"]["feat_valid"])
                 else:
                     halo = data["halo_feats"]
             else:
                 d = h.shape[-1]
-                halo = jnp.zeros((p, nh, d), h.dtype)
+                with device_scope("cache_read"):
+                    halo = jnp.zeros((p, nh, d), h.dtype)
                 with device_scope("tier_pull_uncached"):
                     halo = _scatter(halo, xr["un"]["recv_halo_pos"],
                                     _pull(xr["un"], h, hdt),
@@ -539,19 +540,20 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
                 with device_scope("tier_pull_refresh"):
                     loc_fresh = _pull(xe["loc"], h, hdt)
                     buf_fresh = _build_global(xe["gl"], h, hdt)
-                if use_stale:
-                    loc_use, loc_t = caches["local"][li - 1], xr["loc"]
-                    if host_mode:
-                        buf_use = hostd["gl"][li - 1].astype(h.dtype)
+                with device_scope("cache_read"):
+                    if use_stale:
+                        loc_use, loc_t = caches["local"][li - 1], xr["loc"]
+                        if host_mode:
+                            buf_use = hostd["gl"][li - 1].astype(h.dtype)
+                        else:
+                            buf_use = caches["global"][li - 1]
+                        gl_t = xr["gl"]
                     else:
-                        buf_use = caches["global"][li - 1]
-                    gl_t = xr["gl"]
-                else:
-                    loc_use, loc_t = loc_fresh, xe["loc"]
-                    buf_use, gl_t = buf_fresh, xe["gl"]
-                halo = _scatter(halo, loc_t["recv_halo_pos"], loc_use,
-                                loc_t["recv_valid"])
-                halo = _read_global(gl_t, buf_use, halo)
+                        loc_use, loc_t = loc_fresh, xe["loc"]
+                        buf_use, gl_t = buf_fresh, xe["gl"]
+                    halo = _scatter(halo, loc_t["recv_halo_pos"], loc_use,
+                                    loc_t["recv_valid"])
+                    halo = _read_global(gl_t, buf_use, halo)
                 fresh["local"].append(loc_fresh)
                 fresh["global"].append(buf_fresh)
             with device_scope(f"layer{li}"):
@@ -562,39 +564,42 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
                 hostd=None, l0loc=None):
         logits, fresh = forward(params, caches, xr, xe, use_stale, data,
                                 hostd, l0loc)
-        flat = logits.reshape(-1, logits.shape[-1])
-        loss = cross_entropy_loss(flat, data["labels"],
-                                  data["masks"]["train"])
+        with device_scope("loss"):
+            flat = logits.reshape(-1, logits.shape[-1])
+            loss = cross_entropy_loss(flat, data["labels"],
+                                      data["masks"]["train"])
         return loss, (flat, fresh)
 
     def _metrics_and_caches(loss, flat, fresh, caches, stale_gl,
                             use_stale: bool, emit_fresh: bool, data):
-        metrics = {"loss": loss,
-                   "acc": accuracy(flat, data["labels"],
-                                   data["masks"]["train"])}
-        # Drift compares fresh rows against the stale source of this step.
-        # In host mode that source is the staged host buffer (``stale_gl``
-        # from hostd) — on a host *refresh* there is no staged stale
-        # global at all, so the drift keys are simply not emitted.
-        if emit_fresh and (use_stale or not host_mode):
-            pairs = list(zip(fresh["local"] + fresh["global"],
-                             caches["local"] + stale_gl))
-            drifts = [jnp.max(jnp.abs(a - b)) for a, b in pairs
-                      if a.size]
-            metrics["drift"] = (jnp.max(jnp.stack(drifts)) if drifts
-                                else jnp.zeros(()))
-            # per-row drift stats for the drift-aware planner policy
-            # (max over layers and feature dim; meaningful when xr == xe)
-            n_ex = len(fresh["local"])
-            if n_ex:
-                loc_rows = [jnp.max(jnp.abs(a - b), axis=-1)
-                            for a, b in pairs[:n_ex]]
-                gl_rows = [jnp.max(jnp.abs(a - b), axis=-1)
-                           for a, b in pairs[n_ex:]]
-                metrics["drift_local_rows"] = jnp.max(
-                    jnp.stack(loc_rows), axis=0)          # [P, Rloc]
-                metrics["drift_global_rows"] = jnp.max(
-                    jnp.stack(gl_rows), axis=0)           # [G]
+        with device_scope("loss"):
+            metrics = {"loss": loss,
+                       "acc": accuracy(flat, data["labels"],
+                                       data["masks"]["train"])}
+        with device_scope("cache_update"):
+            # Drift compares fresh rows against the stale source of this step.
+            # In host mode that source is the staged host buffer (``stale_gl``
+            # from hostd) — on a host *refresh* there is no staged stale
+            # global at all, so the drift keys are simply not emitted.
+            if emit_fresh and (use_stale or not host_mode):
+                pairs = list(zip(fresh["local"] + fresh["global"],
+                                 caches["local"] + stale_gl))
+                drifts = [jnp.max(jnp.abs(a - b)) for a, b in pairs
+                          if a.size]
+                metrics["drift"] = (jnp.max(jnp.stack(drifts)) if drifts
+                                    else jnp.zeros(()))
+                # per-row drift stats for the drift-aware planner policy
+                # (max over layers and feature dim; meaningful when xr == xe)
+                n_ex = len(fresh["local"])
+                if n_ex:
+                    loc_rows = [jnp.max(jnp.abs(a - b), axis=-1)
+                                for a, b in pairs[:n_ex]]
+                    gl_rows = [jnp.max(jnp.abs(a - b), axis=-1)
+                               for a, b in pairs[n_ex:]]
+                    metrics["drift_local_rows"] = jnp.max(
+                        jnp.stack(loc_rows), axis=0)          # [P, Rloc]
+                    metrics["drift_global_rows"] = jnp.max(
+                        jnp.stack(gl_rows), axis=0)           # [G]
         if host_mode:
             out_caches = {"local": (fresh["local"] if emit_fresh
                                     else caches["local"]),
@@ -609,9 +614,12 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
                 (loss, (flat, fresh)), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(params, caches, xr, xe,
                                            use_stale, data, hostd, l0loc)
-                new_params, new_state = opt.update(grads, opt_state, params)
-                stale_gl = ([g.astype(jnp.float32) for g in hostd["gl"]]
-                            if use_stale else [])
+                with device_scope("optimizer"):
+                    new_params, new_state = opt.update(grads, opt_state,
+                                                       params)
+                with device_scope("cache_update"):
+                    stale_gl = ([g.astype(jnp.float32) for g in hostd["gl"]]
+                                if use_stale else [])
                 metrics, out_caches = _metrics_and_caches(
                     loss, flat, fresh, caches, stale_gl,
                     use_stale, emit_fresh, data)
@@ -631,7 +639,8 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
             (loss, (flat, fresh)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, caches, xr, xe, use_stale,
                                        data)
-            new_params, new_state = opt.update(grads, opt_state, params)
+            with device_scope("optimizer"):
+                new_params, new_state = opt.update(grads, opt_state, params)
             metrics, out_caches = _metrics_and_caches(
                 loss, flat, fresh, caches, caches["global"],
                 use_stale, emit_fresh, data)
@@ -661,13 +670,9 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
              "tracer": NULL_TRACER}
 
     def wrap(name):
-        ann = f"capgnn/step_{name}"
-
         def stepper(params, opt_state, caches):
             xa = state["xarr"]
-            with host_annotation(ann):
-                return jit_steps[name](params, opt_state, caches, xa, xa,
-                                       data)
+            return jit_steps[name](params, opt_state, caches, xa, xa, data)
         return stepper
 
     if host_mode:
@@ -768,7 +773,6 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
         def wrap_host(name):
             use_gl = name in ("cached", "pipelined")
             emit = name in ("refresh", "pipelined")
-            ann = f"capgnn/step_{name}"
 
             def stepper(params, opt_state, caches):
                 tr = state["tracer"]
@@ -777,9 +781,8 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
                     if use_gl:
                         hostd["gl"] = _take_gl()
                 xa = state["xarr"]
-                with host_annotation(ann):
-                    out = jit_steps[name](params, opt_state, caches, hostd,
-                                          state["l0loc"], xa, xa, data)
+                out = jit_steps[name](params, opt_state, caches, hostd,
+                                      state["l0loc"], xa, xa, data)
                 if emit:
                     new_p, new_s, out_caches, host_out, metrics = out
                     with tr.span("writeback"):
@@ -813,10 +816,9 @@ def make_sim_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
                 hostd = {"l0": _take_l0(), "gl": _take_gl()}
             xr = state["xarr"]
             xe = exchange_arrays(new_xp, include_host=True)
-            with host_annotation("capgnn/step_transition"):
-                new_p, new_s, out_caches, host_out, metrics = (
-                    jit_steps["pipelined"](params, opt_state, caches, hostd,
-                                           state["l0loc"], xr, xe, data))
+            new_p, new_s, out_caches, host_out, metrics = (
+                jit_steps["pipelined"](params, opt_state, caches, hostd,
+                                       state["l0loc"], xr, xe, data))
             state["xarr"] = xe
             state["hostnp"] = _host_np(new_xp)
             # ...while the emitted buffers carry the NEW plan's membership

@@ -52,7 +52,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.kernels.ops import pack_rows
 from repro.models.gnn import GNNConfig, _layer_apply, accuracy, cross_entropy_loss
-from repro.obs.annotations import device_scope, host_annotation
+from repro.obs.annotations import device_scope
 from repro.obs.tracer import NULL_TRACER
 from repro.optim import Optimizer
 
@@ -412,17 +412,13 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
         and stale global reads come from ``hostd["gl"]`` (replicated
         stage of the host-resident buffers) — mirroring the oracle.
         """
-        feats = dsh["feats"][0]                       # [NI, F]
-        halo0 = None if host_mode else dsh["halo_feats"][0]   # [NH, F]
-        adj = build_adj({k: v[0] for k, v in dsh["adj"].items()})
-        i_dev = jax.lax.axis_index(names) if p2p else None
-
         def peer_ring(tier, h):
             payload = pack_rows(h, tier["peer_send_row"][0],
                                 use_pallas=pallas_pack)          # [P, B, d]
             payload = jnp.where(tier["peer_send_valid"][0][..., None],
                                 payload, 0.0)
-            return _PeerRing(_quant(payload), i_dev, p, names)
+            return _PeerRing(_quant(payload), jax.lax.axis_index(names), p,
+                             names)
 
         def peer_collect(tier, blocks, dtype):
             rows = blocks[tier["recv_src_part"][0],
@@ -441,8 +437,8 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
             return jnp.where(tier["recv_valid"][0][..., None], rows, 0.0)
 
         def buf_ring(xa, h):
-            return _BufRing(_quant(h[xa["sh"]["gl"]["send_row"][0]]), i_dev,
-                            p, names)
+            return _BufRing(_quant(h[xa["sh"]["gl"]["send_row"][0]]),
+                            jax.lax.axis_index(names), p, names)
 
         def buf_collect(xa, acc, dtype):
             rows = acc[xa["rep"]["g_src_part"],
@@ -464,32 +460,33 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
             return scatter(halo, gl["read_pos"][0],
                            buf[gl["read_buf_idx"][0]], gl["read_valid"][0])
 
-        h = feats
+        with device_scope("cache_read"):
+            h = dsh["feats"][0]                                   # [NI, F]
         fresh = {"local": [], "global": []}
         pending = []   # (dtype, local _PeerRing, global _BufRing)
         for li, lp in enumerate(params):
             if li == 0:
-                if host_mode:
-                    halo = jnp.zeros((nh, feats.shape[-1]), feats.dtype)
-                    loc_t = xr["sh"]["loc"]
-                    halo = scatter(halo, loc_t["recv_halo_pos"][0],
-                                   l0loc[0].astype(feats.dtype),
-                                   loc_t["recv_valid"][0])
-                    ht = xr["sh"]["host"]
-                    halo = scatter(halo, ht["feat_pos"][0],
-                                   hostd["l0"][0].astype(feats.dtype),
-                                   ht["feat_valid"][0])
-                else:
-                    halo = halo0
+                with device_scope("cache_read"):
+                    if host_mode:
+                        halo = jnp.zeros((nh, h.shape[-1]), h.dtype)
+                        loc_t = xr["sh"]["loc"]
+                        halo = scatter(halo, loc_t["recv_halo_pos"][0],
+                                       l0loc[0].astype(h.dtype),
+                                       loc_t["recv_valid"][0])
+                        ht = xr["sh"]["host"]
+                        halo = scatter(halo, ht["feat_pos"][0],
+                                       hostd["l0"][0].astype(h.dtype),
+                                       ht["feat_valid"][0])
+                    else:
+                        halo = dsh["halo_feats"][0]               # [NH, F]
             else:
                 d = h.shape[-1]
-                halo = jnp.zeros((nh, d), h.dtype)
+                with device_scope("cache_read"):
+                    halo = jnp.zeros((nh, d), h.dtype)
                 un = xr["sh"]["un"]
                 with device_scope("tier_pull_uncached"):
                     halo = scatter(halo, un["recv_halo_pos"][0], pull(un, h),
                                    un["recv_valid"][0])
-                stale_gl = (hostd["gl"][li - 1].astype(h.dtype) if host_mode
-                            else caches["global"][li - 1]) if use_stale else None
                 if defer_refresh and p2p:
                     # issue this boundary's refresh rings on the EMIT plan;
                     # consume stale through the READ plan
@@ -497,26 +494,28 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
                         pending.append((h.dtype,
                                         peer_ring(xe["sh"]["loc"], h),
                                         buf_ring(xe, h)))
-                    loc_use, loc_t = caches["local"][li - 1][0], xr["sh"]["loc"]
-                    buf_use, gl_t = stale_gl, xr["sh"]["gl"]
                 else:
                     with device_scope("tier_pull_refresh"):
                         loc_fresh = pull(xe["sh"]["loc"], h)
                         buf_fresh = build_global(xe, h)
+                    with device_scope("cache_update"):
+                        fresh["local"].append(loc_fresh[None])
+                        fresh["global"].append(buf_fresh)
+                tiers = xr["sh"] if use_stale else xe["sh"]
+                with device_scope("cache_read"):
                     if use_stale:
-                        loc_use, loc_t = (caches["local"][li - 1][0],
-                                          xr["sh"]["loc"])
-                        buf_use, gl_t = stale_gl, xr["sh"]["gl"]
+                        loc_use = caches["local"][li - 1][0]
+                        buf_use = (hostd["gl"][li - 1].astype(h.dtype)
+                                   if host_mode else caches["global"][li - 1])
                     else:
-                        loc_use, loc_t = loc_fresh, xe["sh"]["loc"]
-                        buf_use, gl_t = buf_fresh, xe["sh"]["gl"]
-                    fresh["local"].append(loc_fresh[None])
-                    fresh["global"].append(buf_fresh)
-                halo = scatter(halo, loc_t["recv_halo_pos"][0], loc_use,
-                               loc_t["recv_valid"][0])
-                halo = read_global(gl_t, buf_use, halo)
-            h_local = jnp.concatenate([h, halo], axis=0)
-            with device_scope(f"layer{li}/spmm"):
+                        loc_use, buf_use = loc_fresh, buf_fresh
+                    halo = scatter(halo, tiers["loc"]["recv_halo_pos"][0],
+                                   loc_use, tiers["loc"]["recv_valid"][0])
+                    halo = read_global(tiers["gl"], buf_use, halo)
+            with device_scope("cache_read"):
+                h_local = jnp.concatenate([h, halo], axis=0)
+            with device_scope(f"layer{li}"):
+                adj = build_adj({k: v[0] for k, v in dsh["adj"].items()})
                 h = _layer_apply(cfg, lp, adj, h_local, ni,
                                  is_last=(li == layers - 1))
             # one ring rotation per in-flight refresh, placed right after
@@ -547,11 +546,13 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
         logits, fresh = _device_forward(params, caches, dsh, xr, xe,
                                         use_stale, defer_refresh,
                                         hostd, l0loc)
-        labels = dsh["labels"][0]
-        mask = dsh["train_mask"][0]
-        logp = jax.nn.log_softmax(logits, -1)
-        nll = -jnp.take_along_axis(logp, labels[:, None], -1)[:, 0]
-        return jnp.sum(nll * mask) / total_train, (logits, fresh)
+        with device_scope("loss"):
+            labels = dsh["labels"][0]
+            mask = dsh["train_mask"][0]
+            logp = jax.nn.log_softmax(logits, -1)
+            nll = -jnp.take_along_axis(logp, labels[:, None], -1)[:, 0]
+            loss = jnp.sum(nll * mask) / total_train
+        return loss, (logits, fresh)
 
     def _make_step(use_stale: bool, emit_fresh: bool,
                    defer_refresh: bool = False):
@@ -561,36 +562,42 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
                 _device_loss, has_aux=True)(params, caches, dsh, xr, xe,
                                             use_stale, defer_refresh,
                                             hostd, l0loc)
-            loss = jax.lax.psum(loss, names)
-            grads = jax.lax.psum(grads, names)
-            new_params, new_state = opt.update(grads, opt_state, params)
-            labels = dsh["labels"][0]
-            mask = dsh["train_mask"][0]
-            correct = (jnp.argmax(logits, -1) == labels).astype(jnp.float32)
-            acc = jax.lax.psum(jnp.sum(correct * mask), names) / total_train
+            with device_scope("loss"):
+                labels = dsh["labels"][0]
+                mask = dsh["train_mask"][0]
+                correct = (jnp.argmax(logits, -1) == labels).astype(
+                    jnp.float32)
+                n_correct = jnp.sum(correct * mask)
+            with device_scope("grad_sync"):
+                loss = jax.lax.psum(loss, names)
+                grads = jax.lax.psum(grads, names)
+                acc = jax.lax.psum(n_correct, names) / total_train
+            with device_scope("optimizer"):
+                new_params, new_state = opt.update(grads, opt_state, params)
             metrics = {"loss": loss, "acc": acc}
-            # host refresh has no staged stale global to drift against —
-            # the keys are not emitted there (mirrors the oracle runtime)
-            if emit_fresh and (use_stale or not host_mode):
-                stale_gl = ([g.astype(jnp.float32) for g in hostd["gl"]]
-                            if host_mode else caches["global"])
-                pairs = list(zip(fresh["local"] + fresh["global"],
-                                 caches["local"] + stale_gl))
-                drifts = [jnp.max(jnp.abs(a - b)) for a, b in pairs
-                          if a.size]
-                local_max = (jnp.max(jnp.stack(drifts)) if drifts
-                             else jnp.zeros(()))
-                metrics["drift"] = jax.lax.pmax(local_max, names)
-                n_ex = len(fresh["local"])
-                if n_ex:
-                    # per-row drift stats for the drift-aware planner
-                    metrics["drift_local_rows"] = jnp.max(jnp.stack(
-                        [jnp.max(jnp.abs(a - b), axis=-1)
-                         for a, b in pairs[:n_ex]]), axis=0)   # [1, Rloc]
-                    metrics["drift_global_rows"] = jax.lax.pmax(
-                        jnp.max(jnp.stack(
+            with device_scope("cache_update"):
+                # host refresh has no staged stale global to drift against
+                # — the keys are not emitted there (mirrors the oracle)
+                if emit_fresh and (use_stale or not host_mode):
+                    stale_gl = ([g.astype(jnp.float32) for g in hostd["gl"]]
+                                if host_mode else caches["global"])
+                    pairs = list(zip(fresh["local"] + fresh["global"],
+                                     caches["local"] + stale_gl))
+                    drifts = [jnp.max(jnp.abs(a - b)) for a, b in pairs
+                              if a.size]
+                    local_max = (jnp.max(jnp.stack(drifts)) if drifts
+                                 else jnp.zeros(()))
+                    metrics["drift"] = jax.lax.pmax(local_max, names)
+                    n_ex = len(fresh["local"])
+                    if n_ex:
+                        # per-row drift stats for the drift-aware planner
+                        metrics["drift_local_rows"] = jnp.max(jnp.stack(
                             [jnp.max(jnp.abs(a - b), axis=-1)
-                             for a, b in pairs[n_ex:]]), axis=0), names)
+                             for a, b in pairs[:n_ex]]), axis=0)   # [1, Rloc]
+                        metrics["drift_global_rows"] = jax.lax.pmax(
+                            jnp.max(jnp.stack(
+                                [jnp.max(jnp.abs(a - b), axis=-1)
+                                 for a, b in pairs[n_ex:]]), axis=0), names)
             if host_mode:
                 out_caches = {"local": (fresh["local"] if emit_fresh
                                         else caches["local"]),
@@ -684,13 +691,10 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
              "tracer": NULL_TRACER}
 
     def wrap(name):
-        ann = f"capgnn/step_{name}"
-
         def stepper(params, opt_state, caches):
             xa = state["xarr"]
-            with host_annotation(ann):
-                return jit_steps[name](params, opt_state, caches, xa, xa,
-                                       data_sh)
+            return jit_steps[name](params, opt_state, caches, xa, xa,
+                                   data_sh)
         return stepper
 
     if host_mode:
@@ -783,7 +787,6 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
         def wrap_host(name):
             use_gl = name in ("cached", "pipelined")
             emit = name in ("refresh", "pipelined")
-            ann = f"capgnn/step_{name}"
 
             def stepper(params, opt_state, caches):
                 tr = state["tracer"]
@@ -792,9 +795,8 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
                     if use_gl:
                         hostd["gl"] = _take_gl()
                 xa = state["xarr"]
-                with host_annotation(ann):
-                    out = jit_steps[name](params, opt_state, caches, hostd,
-                                          state["l0loc"], xa, xa, data_sh)
+                out = jit_steps[name](params, opt_state, caches, hostd,
+                                      state["l0loc"], xa, xa, data_sh)
                 if emit:
                     new_p, new_s, out_caches, host_out, metrics = out
                     with tr.span("writeback"):
@@ -824,10 +826,9 @@ def make_spmd_runtime(cfg: GNNConfig, sp: StackedParts, xplan: ExchangePlan,
             xr = state["xarr"]
             xe = spmd_exchange_arrays(new_xp, p2p, mesh, names,
                                       include_host=True)
-            with host_annotation("capgnn/step_transition"):
-                new_p, new_s, out_caches, host_out, metrics = (
-                    jit_steps["pipelined"](params, opt_state, caches, hostd,
-                                           state["l0loc"], xr, xe, data_sh))
+            new_p, new_s, out_caches, host_out, metrics = (
+                jit_steps["pipelined"](params, opt_state, caches, hostd,
+                                       state["l0loc"], xr, xe, data_sh))
             state["xarr"] = xe
             state["hostnp"] = _host_np(new_xp)
             with tr.span("writeback"):

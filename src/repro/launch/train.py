@@ -151,11 +151,12 @@ def run_gnn(args) -> dict:
                            else int(fetch_retries)),
             checksums=checksums)
 
+    device_trace_dir = getattr(args, "device_trace_dir", "")
     tracer = None
-    if getattr(args, "trace", False):
+    if getattr(args, "trace", False) or device_trace_dir:
+        # an enabled tracer's spans land in the device capture too
         from repro.obs import Tracer
         tracer = Tracer()
-    device_trace_dir = getattr(args, "device_trace_dir", "")
     from repro.obs import device_trace
     with device_trace(device_trace_dir):
         params, report = train_capgnn(cfg, runtime, xplan, p, opt,
@@ -194,7 +195,7 @@ def run_gnn(args) -> dict:
         out["faults"] = (faults.spec_string() if faults is not None else "")
         out["faults_injected"] = report.faults_injected
         out["fault_events"] = report.fault_events
-    if tracer is not None:
+    if getattr(args, "trace", False):
         paths = tracer.export(args.trace_dir, prefix="train")
         out["phase_stats"] = report.phase_stats
         out["trace_file"] = paths["trace"]
@@ -413,7 +414,8 @@ def build_parser() -> argparse.ArgumentParser:
     g.add_argument("--device-trace-dir", default="",
                    help="opt-in jax.profiler.trace capture directory for "
                         "device-side timelines (XPlane; open in "
-                        "TensorBoard/Perfetto)")
+                        "TensorBoard/Perfetto); turns the repro.obs "
+                        "tracer on, so the capture holds its spans")
     g.add_argument("--faults", default="",
                    help="fault-injection spec, e.g. "
                         "'grad_nan@3;fetch_drop@2,5:rows=4' — clauses "

@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.nn import glorot, zeros_init
 from repro.graph.graph import Graph
+from repro.obs.annotations import device_scope
 
 __all__ = ["Adjacency", "DenseAdj", "EdgeListAdj", "EllAdj", "HybridAdj",
            "BACKENDS", "GNNConfig", "init_gnn", "gnn_forward",
@@ -256,39 +257,57 @@ def init_gnn(key, cfg: GNNConfig) -> list[dict]:
 
 def _layer_apply(cfg: GNNConfig, p: dict, adj: Adjacency,
                  h_local: jnp.ndarray, n_inner: int, is_last: bool) -> jnp.ndarray:
+    """One layer, its work under the two scopes of a layer
+    (:data:`repro.obs.annotations.LAYER_PARTS`): ``aggregate``, the
+    adjacency product with SAGE's degree normalisation and GAT's edge
+    softmax, and ``transform``, the dense work and the activation."""
     if cfg.model == "gcn":
-        z = adj.spmm(h_local) @ p["w"] + p["b"]
+        with device_scope("aggregate"):
+            agg = adj.spmm(h_local)
+        with device_scope("transform"):
+            z = agg @ p["w"] + p["b"]
     elif cfg.model == "sage":
-        agg = adj.spmm(h_local)
-        agg = agg / jnp.maximum(adj.degree()[:, None], 1.0)
-        z = h_local[:n_inner] @ p["w_self"] + agg @ p["w_neigh"] + p["b"]
+        with device_scope("aggregate"):
+            agg = adj.spmm(h_local)
+            agg = agg / jnp.maximum(adj.degree()[:, None], 1.0)
+        with device_scope("transform"):
+            z = h_local[:n_inner] @ p["w_self"] + agg @ p["w_neigh"] + p["b"]
     elif cfg.model == "gat":
         if not isinstance(adj, EdgeListAdj):
             raise NotImplementedError(
                 f"GAT's edge softmax needs flat edge ids, which the "
                 f"{type(adj).__name__} backend does not expose — build the "
                 "adjacency/runtime with backend='edges' for GAT.")
-        h_heads = (h_local @ p["w"]).reshape(h_local.shape[0], p["a_src"].shape[0], -1)
-        e_src = jnp.einsum("nhd,hd->nh", h_heads, p["a_src"])
-        e_dst = jnp.einsum("nhd,hd->nh", h_heads, p["a_dst"])
-        logits = jax.nn.leaky_relu(e_src[adj.src] + e_dst[adj.dst], 0.2)
-        # segment softmax over incoming edges of each inner vertex
-        seg_max = jax.ops.segment_max(logits, adj.dst, num_segments=adj.n_rows)
-        ex = jnp.exp(logits - seg_max[adj.dst])
-        denom = jax.ops.segment_sum(ex, adj.dst, num_segments=adj.n_rows)
-        att = ex / jnp.maximum(denom[adj.dst], 1e-9)
-        outs = []
-        for hh in range(att.shape[1]):
-            outs.append(adj.spmm_at(att[:, hh], h_heads[:, hh, :]))
-        z = jnp.concatenate(outs, axis=-1) @ p["proj"]
+        with device_scope("transform"):
+            h_heads = (h_local @ p["w"]).reshape(h_local.shape[0],
+                                                 p["a_src"].shape[0], -1)
+        with device_scope("aggregate"):
+            e_src = jnp.einsum("nhd,hd->nh", h_heads, p["a_src"])
+            e_dst = jnp.einsum("nhd,hd->nh", h_heads, p["a_dst"])
+            logits = jax.nn.leaky_relu(e_src[adj.src] + e_dst[adj.dst], 0.2)
+            # segment softmax over incoming edges of each inner vertex
+            seg_max = jax.ops.segment_max(logits, adj.dst,
+                                          num_segments=adj.n_rows)
+            ex = jnp.exp(logits - seg_max[adj.dst])
+            denom = jax.ops.segment_sum(ex, adj.dst, num_segments=adj.n_rows)
+            att = ex / jnp.maximum(denom[adj.dst], 1e-9)
+            outs = []
+            for hh in range(att.shape[1]):
+                outs.append(adj.spmm_at(att[:, hh], h_heads[:, hh, :]))
+            agg = jnp.concatenate(outs, axis=-1)
+        with device_scope("transform"):
+            z = agg @ p["proj"]
     elif cfg.model == "gin":
-        agg = adj.spmm(h_local)
-        z = (1.0 + p["eps"]) * h_local[:n_inner] + agg
-        z = jax.nn.relu(z @ p["w1"] + p["b1"]) @ p["w2"] + p["b2"]
+        with device_scope("aggregate"):
+            agg = adj.spmm(h_local)
+        with device_scope("transform"):
+            z = (1.0 + p["eps"]) * h_local[:n_inner] + agg
+            z = jax.nn.relu(z @ p["w1"] + p["b1"]) @ p["w2"] + p["b2"]
     else:
         raise ValueError(cfg.model)
     if not is_last:
-        z = jax.nn.relu(z)
+        with device_scope("transform"):
+            z = jax.nn.relu(z)
     return z
 
 

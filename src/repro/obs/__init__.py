@@ -16,24 +16,26 @@ after summing into report totals:
   ``trace_event`` JSON (loads in Perfetto: spans as duration events,
   counters as counter tracks, one track per worker) written under
   ``experiments/``.
-- device-side visibility: :func:`device_scope` (``jax.named_scope``
-  inside jitted code), :func:`host_annotation`
-  (``jax.profiler.TraceAnnotation`` around dispatch sites) and
-  :func:`device_trace` (opt-in ``jax.profiler.trace`` capture dir).
+- device-side visibility: :func:`device_scope` opens a scope of the
+  step's one named-scope vocabulary (:data:`PHASES`, :data:`LAYER_PARTS`)
+  inside jitted code; an enabled :class:`Tracer` enters a
+  ``jax.profiler.TraceAnnotation("repro/<name>")`` for each span, so a
+  capture (:func:`device_trace`, opt-in ``jax.profiler.trace`` dir) holds
+  the program's spans on the device ops' clock.
 
 ``python -m repro.obs.check trace.json`` validates an exported timeline
 (the CI smoke gate).
 """
 from .tracer import (NULL_TRACER, SPAN_KINDS, STEP_KINDS, Span,
                      StepCounters, Tracer, device_peak_bytes)
-from .annotations import device_scope, device_trace, host_annotation
+from .annotations import LAYER_PARTS, PHASES, device_scope, device_trace
 from .export import (chrome_trace_events, validate_chrome_trace,
                      write_chrome_trace, write_metrics_jsonl)
 
 __all__ = [
     "Tracer", "Span", "StepCounters", "NULL_TRACER",
     "STEP_KINDS", "SPAN_KINDS", "device_peak_bytes",
-    "device_scope", "host_annotation", "device_trace",
+    "PHASES", "LAYER_PARTS", "device_scope", "device_trace",
     "chrome_trace_events", "write_chrome_trace", "write_metrics_jsonl",
     "validate_chrome_trace",
 ]

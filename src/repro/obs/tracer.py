@@ -6,17 +6,24 @@ opens inside it, so two step kinds can never interleave.  Counters are
 typed :class:`StepCounters` records, one per training step, whose totals
 reproduce the report/plan accounting exactly (asserted in tests).
 
+Each span of an enabled tracer is also a
+``jax.profiler.TraceAnnotation("repro/<name>")``: a profiler capture taken
+while it runs holds the program's spans on its host plane, on the clock of
+the device operations.
+
 Zero-overhead contract: a disabled tracer (``Tracer(enabled=False)`` or
 the shared :data:`NULL_TRACER`) allocates nothing per call — ``span()``
 returns one shared reusable no-op context manager, ``count()`` /
-``fence()`` return immediately, and no ``jax.block_until_ready`` is ever
-issued.  Fencing happens only on an *enabled* tracer, so span durations
-measure completed device work rather than async dispatch.
+``fence()`` return immediately, and no ``jax.block_until_ready`` or
+TraceMe is ever issued.  Fencing happens only on an *enabled* tracer, so
+span durations measure completed device work rather than async dispatch.
 """
 from __future__ import annotations
 
 import dataclasses
 import time
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Tracer", "Span", "StepCounters", "NULL_TRACER",
            "STEP_KINDS", "SPAN_KINDS", "device_memory_stats",
@@ -132,8 +139,9 @@ _NOOP = _NoopSpan()
 
 
 class _OpenSpan:
-    """Context manager recording one span on exit (enabled path)."""
-    __slots__ = ("tr", "name", "kind", "step", "args", "t0", "depth")
+    """Context manager recording one span on exit (enabled path), inside
+    a profiler annotation of the same name."""
+    __slots__ = ("tr", "name", "kind", "step", "args", "t0", "depth", "ann")
 
     def __init__(self, tr: "Tracer", name: str, kind: str,
                  step: int | None, args: dict | None):
@@ -143,11 +151,14 @@ class _OpenSpan:
     def __enter__(self):
         self.depth = len(self.tr._stack)
         self.tr._stack.append(self)
+        self.ann = TraceAnnotation(f"repro/{self.name}")
+        self.ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self.t0
+        self.ann.__exit__(*exc)
         top = self.tr._stack.pop()
         if top is not self:            # interleaved exit — structural bug
             raise RuntimeError(

@@ -15,8 +15,10 @@ Coverage layers:
   per-worker counter tracks), the JSONL metrics stream reconstructs the
   counter records;
 - zero overhead: a run without a tracer issues no
-  ``jax.block_until_ready`` beyond the untraced baseline and the donated
-  steps stay warning-free.
+  ``jax.block_until_ready`` and no TraceMe beyond the untraced baseline
+  and the donated steps stay warning-free;
+- profiler clock: an enabled tracer's spans appear as ``repro/<name>``
+  host events in a ``jax.profiler`` capture, a disabled one's do not.
 """
 import dataclasses
 import json
@@ -325,13 +327,21 @@ def test_traced_export_validates(tmp_path):
 
 def test_untraced_run_adds_no_sync(monkeypatch):
     """tracer=None and a disabled tracer issue zero block_until_ready
-    calls from the training loop (the per-step float() is the only sync),
-    and donation stays clean."""
+    calls and no TraceMe from the training loop (the per-step float() is
+    the only sync), and donation stays clean."""
     import jax
+    import repro.obs.tracer as tracer_mod
     real = jax.block_until_ready
-    calls = []
+    calls, traceme = [], []
     monkeypatch.setattr(jax, "block_until_ready",
                         lambda x: calls.append(1) or real(x))
+    real_ann = jax.profiler.TraceAnnotation
+
+    def counted(name, **kw):
+        traceme.append(name)
+        return real_ann(name, **kw)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", counted)
+    monkeypatch.setattr(tracer_mod, "TraceAnnotation", counted)
     with warnings.catch_warnings(record=True) as w:
         warnings.simplefilter("always")
         _, rep_none, _ = _traced_run(tracer=None, epochs=4)
@@ -340,14 +350,66 @@ def test_untraced_run_adds_no_sync(monkeypatch):
                                     epochs=4)
         n_off = len(calls) - n_none
     assert n_none == 0 and n_off == 0
+    assert traceme == []
     bad = [str(x.message) for x in w if "donat" in str(x.message).lower()]
     assert not bad, bad
     assert rep_none.phase_stats is None and rep_off.phase_stats is None
     np.testing.assert_allclose(rep_none.losses, rep_off.losses)
-    # ... and an enabled tracer fences once per step
+    # ... and an enabled tracer fences once per step, and marks each span
     calls.clear()
     tr, _, _ = _traced_run(epochs=4)
     assert len(calls) == 4
+    assert traceme == [f"repro/{s.name}" for s in
+                       sorted(tr.spans, key=lambda s: s.t0)]
+
+
+def _capture_names(trace_dir) -> set:
+    """Names of the host events of the one capture under ``trace_dir``."""
+    import jax
+    path, = trace_dir.rglob("*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    return {ev.name for plane in pd.planes if plane.name == "/host:CPU"
+            for line in plane.lines for ev in line.events}
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_spans_on_the_profiler_clock(tmp_path, enabled):
+    """An enabled tracer's spans are host events ``repro/<name>`` of a
+    profiler capture, nested as the spans are; a disabled tracer's are
+    not there."""
+    import jax
+    import jax.numpy as jnp
+    tr = Tracer(enabled=enabled, fence=False)
+    f = jax.jit(lambda x: (x @ x).sum())
+    x = jnp.ones((32, 32))
+    f(x).block_until_ready()
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.step_span("refresh", 0):
+            with tr.span("l0_stage"):
+                f(x).block_until_ready()
+        with tr.step_span("cached", 1):
+            f(x).block_until_ready()
+    names = {n for n in _capture_names(tmp_path) if n.startswith("repro/")}
+    want = {"repro/refresh", "repro/l0_stage", "repro/cached"}
+    assert names == (want if enabled else set())
+
+
+def test_launcher_device_trace_carries_spans(tmp_path):
+    """``launch.train gnn --device-trace-dir`` turns the tracer on, so its
+    capture holds the step spans (without ``--trace`` nothing is
+    exported)."""
+    import argparse
+    from repro.launch.train import run_gnn
+    out = run_gnn(argparse.Namespace(
+        dataset="flickr", scale=0.008, feat_dim=16, model="gcn",
+        backend="edges", hidden=16, layers=2, parts=2, partitioner="metis",
+        epochs=3, lr=0.01, jaca=True, rapa=False, pipeline=False,
+        refresh_every=2, adaptive_staleness=False, cpu_cache_gib=1.0,
+        seed=0, ckpt_dir="", resume=False,
+        device_trace_dir=str(tmp_path / "capture")))
+    assert "trace_file" not in out
+    names = _capture_names(tmp_path / "capture")
+    assert {"repro/refresh", "repro/cached"} <= names
 
 
 # ------------------------------------------- SPMD runtimes (forced mesh)
