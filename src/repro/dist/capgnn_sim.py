@@ -198,6 +198,13 @@ def make_adj_builder(sp: StackedParts, backend: str, stacked: bool = False):
     partition runs one unbatched SpMM: a vmapped segment-sum lowers to a
     batched scatter whose TPU compile time grows with the edge count.
 
+    A mesh shard needs every partition's COO list padded to one length; a
+    flattened block-diagonal list has no rectangle to fill, so with
+    ``stacked=True`` the COO lists (the ``edges`` backend's, the
+    ``hybrid`` tail) keep only their real entries (``dst < NI``, the test
+    of :attr:`StackedParts.n_edges`), in partition order.  The padding
+    slots would cost a gather, a multiply and a scatter-add each.
+
     Every backend aggregates over the identical edge set (the packs are
     built from the same remapped edge lists at stack time), so swapping the
     backend changes kernel shape only — logits, gradients, and the exchange
@@ -209,26 +216,24 @@ def make_adj_builder(sp: StackedParts, backend: str, stacked: bool = False):
 
     def cols(c):
         if not stacked:
-            return jnp.asarray(c)
+            return c
         part = np.arange(p).reshape((p,) + (1,) * (c.ndim - 1))
         flat = np.where(c < ni, part * ni + c, p * ni + part * nh + c - ni)
-        return jnp.asarray(flat.reshape((-1,) + c.shape[2:]), jnp.int32)
-
-    def rows(r):
-        # padding rows (== NI) stay out of range, so the scatter drops them
-        if not stacked:
-            return jnp.asarray(r)
-        part = np.arange(p)[:, None]
-        return jnp.asarray(np.where(r < ni, part * ni + r, n_rows).reshape(-1),
-                           jnp.int32)
+        return flat.reshape((-1,) + c.shape[2:]).astype(np.int32)
 
     def vals(v):
-        v = np.asarray(v)
-        return jnp.asarray(v.reshape((-1,) + v.shape[2:]) if stacked else v)
+        return v.reshape((-1,) + v.shape[2:]) if stacked else v
+
+    def coo(src, dst, w):
+        if not stacked:
+            return src, dst, w
+        real = (dst < ni).reshape(-1)
+        flat_dst = (np.arange(p)[:, None] * ni + dst).reshape(-1)
+        return cols(src)[real], flat_dst[real].astype(np.int32), vals(w)[real]
 
     if backend == "edges":
-        leaves = {"src": cols(sp.e_src), "dst": rows(sp.e_dst),
-                  "w": vals(sp.e_w)}
+        src, dst, w = coo(sp.e_src, sp.e_dst, sp.e_w)
+        leaves = {"src": src, "dst": dst, "w": w}
 
         def build(lv):
             return EdgeListAdj(lv["src"], lv["dst"], lv["w"], n_rows, n_cols)
@@ -238,15 +243,14 @@ def make_adj_builder(sp: StackedParts, backend: str, stacked: bool = False):
         def build(lv):
             return EllAdj(lv["cols"], lv["vals"], n_cols)
     else:  # hybrid
+        src, dst, w = coo(sp.ell.tail_src, sp.ell.tail_dst, sp.ell.tail_w)
         leaves = {"cols": cols(sp.ell.cols), "vals": vals(sp.ell.vals),
-                  "tail_src": cols(sp.ell.tail_src),
-                  "tail_dst": rows(sp.ell.tail_dst),
-                  "tail_w": vals(sp.ell.tail_w)}
+                  "tail_src": src, "tail_dst": dst, "tail_w": w}
 
         def build(lv):
             return HybridAdj(lv["cols"], lv["vals"], lv["tail_src"],
                              lv["tail_dst"], lv["tail_w"], n_cols)
-    return leaves, build
+    return {k: jnp.asarray(v) for k, v in leaves.items()}, build
 
 
 def stacked_rows(h: jnp.ndarray, halo: jnp.ndarray) -> jnp.ndarray:
@@ -321,8 +325,14 @@ class SimRuntime:
 
     def padding_stats(self) -> dict:
         """Valid vs padded stacked-row counts (see
-        :meth:`repro.dist.StackedParts.padding_stats`)."""
-        return self.stacked.padding_stats() if self.stacked else {}
+        :meth:`repro.dist.StackedParts.padding_stats`), with the edge rows
+        the stacked aggregation processes: its COO entries and ELL slots."""
+        if self.stacked is None:
+            return {}
+        adj = self.data["adj"]
+        slots = sum(int(adj[k].size) for k in ("src", "cols", "tail_src")
+                    if k in adj)
+        return self.stacked.padding_stats(edge_slots=slots)
 
     def set_tracer(self, tracer) -> None:
         """Attach a :class:`repro.obs.Tracer`: the plain-Python stepper

@@ -678,15 +678,19 @@ class StackedParts:
         ``dst == n_inner_max``."""
         return (self.e_dst < self.n_inner_max).sum(axis=1).astype(np.int64)
 
-    def padding_stats(self) -> dict:
+    def padding_stats(self, edge_slots: int | None = None) -> dict:
         """Valid vs padded slot counts of the rectangular stacked layout —
         the waste uneven partitioning is judged on in
-        ``benchmarks/heterogeneous.py``."""
+        ``benchmarks/heterogeneous.py``.  ``edge_slots`` replaces the
+        ``[P, ME]`` edge rectangle with the edge rows an aggregation
+        actually processes."""
         p = self.num_parts
+        if edge_slots is None:
+            edge_slots = p * int(self.e_src.shape[1])
         rows = {
             "inner": (int(self.inner_valid.sum()), p * self.n_inner_max),
             "halo": (int(self.halo_valid.sum()), p * self.n_halo_max),
-            "edges": (int(self.n_edges.sum()), p * int(self.e_src.shape[1])),
+            "edges": (int(self.n_edges.sum()), int(edge_slots)),
         }
         out = {}
         valid = total = 0

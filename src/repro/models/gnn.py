@@ -150,7 +150,7 @@ class HybridAdj(Adjacency):
     The regular part is packed to the degree quantile; overflow edges of
     heavy rows live in a COO tail aggregated by segment-sum.  Padded tail
     entries carry ``tail_dst == n_rows`` and are dropped by the scatter, so
-    the tail arrays may be padded to a static width (stacked runtimes).
+    the tail arrays may be padded to a static width (mesh shards).
     """
     cols: jnp.ndarray      # [n_rows, max_deg] local col ids (padded)
     vals: jnp.ndarray      # [n_rows, max_deg] weights (0 at padding)

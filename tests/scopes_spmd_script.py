@@ -7,7 +7,9 @@ checks that
   ``repro.obs.annotations.PHASES``, layer parts inside a layer;
 - both runtimes open the same phases, save the SPMD runtime's
   ``grad_sync`` and, with the p2p transport, the pipelined step's
-  ``refresh_ring_*`` in place of its ``tier_pull_refresh``.
+  ``refresh_ring_*`` in place of its ``tier_pull_refresh``;
+- the SPMD runtime's ``padding_stats`` keeps the ``[P, ME]`` edge
+  rectangle its shards pad to, while the sim's counts no padded edge row.
 
 Invoked as:  python tests/scopes_spmd_script.py [--transport allgather|p2p]
 Prints OK and exits zero on success.
@@ -63,6 +65,9 @@ def main():
                         num_layers=3)
         sim = make_sim_runtime(cfg, sp, xplan, opt, spec=spec)
         spmd = make_spmd_runtime(cfg, sp, xplan, opt, mesh, spec=spec)
+        assert spmd.padding_stats() == sp.padding_stats()
+        assert sp.padding_stats()["edges_padded_rows"] > 0
+        assert sim.padding_stats()["edges_padded_rows"] == 0
         params = init_gnn(jax.random.PRNGKey(0), cfg)
         caches = init_caches(cfg, xplan, parts)
         for flavour in ("refresh", "cached", "pipelined"):

@@ -8,8 +8,9 @@ import pytest
 
 from repro.core import PROFILES, build_cache_plan, cal_capacity
 from repro.data.gnn_data import FullBatchTask, split_masks
-from repro.dist import (build_exchange_plan, init_caches, make_sim_runtime,
-                        stack_partitions, train_capgnn)
+from repro.dist import (TrainSpec, build_exchange_plan, init_caches,
+                        make_sim_runtime, stack_partitions, train_capgnn)
+from repro.dist.capgnn_sim import make_adj_builder
 from repro.graph import (build_partition, metis_partition, rmat,
                         symmetric_normalize, synth_features)
 from repro.models.gnn import (DenseAdj, EdgeListAdj, EllAdj, GNNConfig,
@@ -191,3 +192,111 @@ def test_train_capgnn_backend_comm_bytes_identical(backend):
     assert rep_b.refresh_steps == rep_e.refresh_steps
     np.testing.assert_allclose(rep_b.losses, rep_e.losses,
                                rtol=1e-4, atol=1e-4)
+
+
+# ------------------------------------------------- stacked COO lists
+
+def _padded_stacked_edges(sp):
+    """The ``[P, ME]`` edge rectangle flattened into the stacked row space,
+    padding slots routed to the dropped row ``P*NI``."""
+    p, ni, nh = sp.num_parts, sp.n_inner_max, sp.n_halo_max
+    part = np.arange(p)[:, None]
+    src = np.where(sp.e_src < ni, part * ni + sp.e_src,
+                   p * ni + part * nh + sp.e_src - ni)
+    dst = np.where(sp.e_dst < ni, part * ni + sp.e_dst, p * ni)
+    return {"src": jnp.asarray(src.reshape(-1), jnp.int32),
+            "dst": jnp.asarray(dst.reshape(-1), jnp.int32),
+            "w": jnp.asarray(sp.e_w.reshape(-1))}
+
+
+def test_stacked_coo_lists_hold_real_edges_only():
+    """The stacked builder flattens the real COO entries alone, in
+    partition order; the per-partition (mesh) leaves stay ``[P, ME]``."""
+    task, ps = _task_and_parts()
+    sp = stack_partitions(ps, task, backend="hybrid")
+    p, ni = sp.num_parts, sp.n_inner_max
+    assert len(set(sp.n_edges.tolist())) > 1       # uneven parts: padding
+    assert int(sp.n_edges.sum()) < sp.e_dst.size
+
+    leaves, _ = make_adj_builder(sp, "edges", stacked=True)
+    padded = _padded_stacked_edges(sp)
+    real = np.asarray(padded["dst"]) < p * ni
+    assert leaves["src"].shape == (int(sp.n_edges.sum()),)
+    for k in ("src", "dst", "w"):
+        np.testing.assert_array_equal(np.asarray(leaves[k]),
+                                      np.asarray(padded[k])[real], err_msg=k)
+    assert int(np.asarray(leaves["dst"]).max()) < p * ni
+
+    hyb, _ = make_adj_builder(sp, "hybrid", stacked=True)
+    n_tail = int((sp.ell.tail_dst < ni).sum())
+    assert 0 < n_tail < sp.ell.tail_dst.size
+    for k in ("tail_src", "tail_dst", "tail_w"):
+        assert hyb[k].shape == (n_tail,), k
+    td = np.asarray(hyb["tail_dst"])
+    assert td.min() >= 0 and td.max() < p * ni
+    assert hyb["cols"].shape == (p * ni, sp.ell.max_deg)
+
+    mesh, _ = make_adj_builder(sp, "edges")
+    for k, a in (("src", sp.e_src), ("dst", sp.e_dst), ("w", sp.e_w)):
+        np.testing.assert_array_equal(np.asarray(mesh[k]), a, err_msg=k)
+    mesh_h, _ = make_adj_builder(sp, "hybrid")
+    np.testing.assert_array_equal(np.asarray(mesh_h["tail_dst"]),
+                                  sp.ell.tail_dst)
+
+
+@pytest.mark.parametrize("model", ["gcn", "sage"])
+def test_sim_steps_match_padded_edge_list(model):
+    """A refresh and a cached step over the real edges give the loss and
+    gradients of the same steps fed the padded ``[P*ME]`` list."""
+    task, ps, cfg, xplan = _sim_fixture(model=model)
+    sp = stack_partitions(ps, task)
+    opt = sgd(1.0)            # the parameters' change is the gradient
+    params = init_gnn(jax.random.PRNGKey(5), cfg)
+
+    def run(rt):
+        caches = init_caches(cfg, xplan, ps.num_parts)
+        out = []
+        p0, s0 = params, opt.init(params)
+        for step in (rt.step_refresh, rt.step_cached):
+            p1, s0, caches, m = step(p0, s0, caches)
+            out.append((float(m["loss"]),
+                        [np.asarray(a) - np.asarray(b) for a, b in
+                         zip(jax.tree.leaves(p1), jax.tree.leaves(p0))]))
+            p0 = p1
+        return out
+
+    rt = make_sim_runtime(cfg, sp, xplan, opt, spec=TrainSpec(donate=False))
+    real = run(rt)
+    rt.data["adj"] = _padded_stacked_edges(sp)
+    padded = run(rt)
+    for (lr, gr), (lp, gp) in zip(real, padded):
+        assert lr == pytest.approx(lp, rel=1e-6)
+        for a, b in zip(gr, gp):
+            np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-9)
+
+
+def test_sim_padding_stats_count_processed_edge_rows():
+    """The sim runtime reports the edge rows its aggregation processes:
+    no padded edge row for the stacked edge list; the layout's own
+    statistics (the SPMD runtime's) keep the ``[P, ME]`` rectangle."""
+    task, ps, cfg, xplan = _sim_fixture()
+    sp = stack_partitions(ps, task, backend="hybrid")
+    rect = sp.padding_stats()
+    n_real = int(sp.n_edges.sum())
+    assert rect["edges_valid_rows"] == n_real
+    assert rect["edges_padded_rows"] == sp.e_dst.size - n_real > 0
+
+    stats = make_sim_runtime(cfg, sp, xplan, adam(1e-2),
+                             spec=TrainSpec()).padding_stats()
+    assert stats["edges_valid_rows"] == n_real
+    assert stats["edges_padded_rows"] == 0
+    for k in ("inner_valid_rows", "inner_padded_rows", "halo_valid_rows",
+              "halo_padded_rows"):
+        assert stats[k] == rect[k], k
+    assert stats["waste_frac"] < rect["waste_frac"]
+
+    hyb = make_sim_runtime(cfg, sp, xplan, adam(1e-2),
+                           spec=TrainSpec(backend="hybrid")).padding_stats()
+    ell_slots = sp.ell.cols.size
+    assert hyb["edges_padded_rows"] == ell_slots - int(
+        (sp.ell.vals != 0).sum())
