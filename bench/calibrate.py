@@ -9,13 +9,16 @@ process on the cell's chips and at its own size:
   on (``halo_dtype: bf16``);
 - the faults the cell can have, planted under the timed path: half of the
   training vertices left out of the loss, the exchange left out, a step
-  that returns its state unchanged.
+  that returns its state unchanged, and on an ``spmd`` traffic the
+  gradient ``psum`` left out.
 
     python3 bench/calibrate.py --workload gcn-flickr.capgnn --seeds 12
 
 Prints one JSON line per (variant, seed) and a summary line: the largest
 reading of each number over the program's seeds and the smallest over
-each control and fault.  Benchmark runs never run this.
+each control and fault.  ``--variants`` runs only the variants named
+(the controls of the reference need the program's variant among them).
+Benchmark runs never run this.
 """
 import argparse
 import dataclasses
@@ -34,6 +37,8 @@ def main(argv) -> int:
     ap.add_argument("--control-seeds", type=int, default=4)
     ap.add_argument("--fault-seeds", type=int, default=3)
     ap.add_argument("--first-seed", type=int, default=2**31 + 101)
+    ap.add_argument("--variants", default="",
+                    help="comma-separated variants to run (default: all)")
     ap.add_argument("--precisions", default="",
                     help="comma-separated matmul precisions to run the "
                          "program at (default: the configuration's)")
@@ -78,8 +83,15 @@ def main(argv) -> int:
             ref_cache[seed] = ref32.readings(seed, kinds)
         return ref_cache[seed]
 
+    only = set(filter(None, args.variants.split(",")))
+
+    def want(variant):
+        return not only or variant in only
+
     def program(variant, n_seeds, c=cell, plant_layout=None,
                 plant_runtime=None):
+        if not want(variant):
+            return
         t = time.perf_counter()
         rt, layout, cfg, opt = cellmod.build_runtime(c, art, plant_layout)
         if plant_runtime is not None:
@@ -110,6 +122,8 @@ def main(argv) -> int:
                      ("control_reference_high", {"precision": "high"}),
                      ("control_reference_default",
                       {"precision": "default"})):
+        if not want(name):
+            continue
         ctl = hm.Reference(cell, graph, **kw)
         for seed in seeds[:args.control_seeds]:
             record(name, seed, ctl.readings(seed, kinds_of[seed]),
@@ -122,6 +136,10 @@ def main(argv) -> int:
             plant_runtime=faults.no_exchange)
     program("fault_unchanged", args.fault_seeds,
             plant_runtime=faults.unchanged)
+    if cell.traffic["runtime"] == "spmd":
+        for name in faults.SPMD_FAULTS:
+            program(f"fault_{name}", args.fault_seeds,
+                    plant_runtime=faults.RUNTIME_FAULTS[name])
 
     out = {}
     for variant, rows in summary.items():

@@ -46,10 +46,12 @@ class Cell:
     limits: dict
     per_layer: list
     rehearsal: bool
+    # where the plain reference's model modules are found
+    reference_dir: Path = BENCH / "reference"
 
     @property
     def model(self):
-        return load_module(BENCH / "reference" / f"{self.config['model']}.py",
+        return load_module(self.reference_dir / f"{self.config['model']}.py",
                            f"bench_reference_{self.config['model']}")
 
     @property
@@ -66,21 +68,24 @@ def _read_json(path: Path) -> dict:
 def load_cell(name: str) -> Cell:
     """The workload ``name`` of ``BENCHMARK.json``, or ``<config>.<traffic>``
     for a configuration file marked ``"rehearsal": true`` (a CPU rehearsal
-    of the harness, which is no cell)."""
+    of the harness, which is no cell; an ``spmd`` traffic rehearses on one
+    CPU device per partition)."""
     bench = _read_json(ROOT / "BENCHMARK.json")
     wl = {w["name"]: w for w in bench["workloads"]}.get(name)
     if wl is not None:
         entry = {c["name"]: c for c in bench["configs"]}[wl["config"]]
         config = _read_json(ROOT / entry["file"])
-        traffic_name, chips, rehearsal = wl["traffic"], wl["chips"], False
+        traffic = _read_json(BENCH / "traffic" / f"{wl['traffic']}.json")
+        chips, rehearsal = wl["chips"], False
     else:
         cname, _, traffic_name = name.partition(".")
         path = BENCH / "configs" / f"{cname}.json"
         config = _read_json(path) if path.is_file() else {}
         if not config.get("rehearsal"):
             raise SystemExit(f"no workload {name!r} in BENCHMARK.json")
-        chips, rehearsal = 1, True
-    traffic = _read_json(BENCH / "traffic" / f"{traffic_name}.json")
+        traffic = _read_json(BENCH / "traffic" / f"{traffic_name}.json")
+        chips = config["parts"] if traffic["runtime"] == "spmd" else 1
+        rehearsal = True
     per_layer = [m for m in bench["per_layer"]
                  if rehearsal or name in m.get("workloads", [name])]
     limits_file = BENCH / "limits" / f"{name}.json"
@@ -175,26 +180,31 @@ def load_partition(cell: Cell) -> tuple[dict, float, bool]:
 # ---------------------------------------------------------------------------
 
 def model_config(cell: Cell):
+    """The program's model: every configuration key that names a
+    ``GNNConfig`` field, with ``feat_dim`` and ``num_classes`` as its
+    input and output widths."""
     from repro.models.gnn import GNNConfig
     c = cell.config
-    return GNNConfig(model=c["model"], in_dim=c["feat_dim"],
-                     hidden_dim=c["hidden_dim"], out_dim=c["num_classes"],
-                     num_layers=c["num_layers"])
+    fields = {f.name for f in dataclasses.fields(GNNConfig)} - {"in_dim",
+                                                                "out_dim"}
+    return GNNConfig(**{k: v for k, v in c.items() if k in fields},
+                     in_dim=c["feat_dim"], out_dim=c["num_classes"])
 
 
 def build_runtime(cell: Cell, art: dict, plant_layout=None):
     """The runtime the traffic names, built through
     ``repro.dist.strategy``: ``sim``, the partitions stacked on one device,
-    is the only one a cell runs so far.  ``plant_layout`` (tests and the
-    calibration only) may alter the layout before the runtime is built."""
+    or ``spmd``, one partition per device over a mesh of as many devices.
+    ``plant_layout`` (tests and the calibration only) may alter the layout
+    before the runtime is built."""
     from repro.dist.spec import TrainSpec
     from repro.dist.strategy import get_strategy
     from repro.optim import adam
 
     t = cell.traffic
-    if t["runtime"] != "sim":
+    if t["runtime"] not in ("sim", "spmd"):
         raise SystemExit(f"runtime {t['runtime']!r}: the harness drives "
-                         f"only the sim runtime")
+                         f"the sim and spmd runtimes")
     spec = TrainSpec(strategy="halo_1d", backend=t["backend"],
                      transport=t["transport"], features=t["features"],
                      halo_dtype=t["halo_dtype"], pipeline=t["pipeline"],
@@ -208,7 +218,13 @@ def build_runtime(cell: Cell, art: dict, plant_layout=None):
         layout = plant_layout(layout)
     cfg = model_config(cell)
     opt = adam(cell.config["lr"])
-    rt = strat.make_sim_runtime(cfg, layout, opt, spec)
+    if t["runtime"] == "sim":
+        rt = strat.make_sim_runtime(cfg, layout, opt, spec)
+    else:
+        import jax
+        p = layout.num_parts
+        mesh = jax.make_mesh((p,), ("data",), devices=jax.devices()[:p])
+        rt = strat.make_spmd_runtime(cfg, layout, opt, spec, mesh)
     return rt, layout, cfg, opt
 
 
@@ -226,12 +242,16 @@ class Trainer:
     """The epochs of ``repro.dist.train_capgnn``, one call at a time: the
     staleness controller picks ``step_refresh`` / ``step_cached`` /
     ``step_pipelined`` in the order ``train_capgnn`` calls them, and every
-    epoch ends on the loss reaching the host.  The wire bytes of each epoch
-    are the plan's exact count, as ``train_capgnn`` accounts them."""
+    epoch ends on the loss reaching the host.  The cache tiers start as
+    zeros laid out as the runtime's own first tiers (``caches0``).  The
+    wire bytes of each epoch are the plan's exact count, as
+    ``train_capgnn`` accounts them."""
 
     def __init__(self, rt, layout, cfg, opt, traffic, params, tracer=None):
+        import jax
+        import jax.numpy as jnp
         from repro.core import StalenessController
-        from repro.dist.capgnn_sim import _step_rows, init_caches
+        from repro.dist.capgnn_sim import _step_rows
         from repro.obs.tracer import NULL_TRACER
 
         self.rt, self.xplan = rt, layout.xplan
@@ -239,8 +259,8 @@ class Trainer:
         self.ctl = StalenessController(refresh_every=traffic["refresh_every"])
         self.tr = tracer if tracer is not None else NULL_TRACER
         opt_state = opt.init(params)
-        caches = init_caches(cfg, layout.xplan, layout.num_parts,
-                             features=traffic["features"])
+        # a copy: the steps donate the caches they are given
+        caches = jax.tree.map(jnp.copy, rt.caches0)
         self.state = (params, opt_state, caches)
         self.dim_bytes = sum(d * rt.halo_dtype_bytes for d in rt.comm_dims)
         self.rows = {r: _step_rows(layout.xplan, layout.xplan, refresh=r)

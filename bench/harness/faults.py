@@ -1,5 +1,8 @@
 """Faults planted under the timed path, for the checks that show the
 comparison catches them (``bench/tests`` and ``bench/calibrate.py``).
+Each but ``no_grad_sync`` plants into either halo runtime, ``sim`` or
+``spmd``: they touch only what the two share (the layout,
+``_state["xarr"]``, ``data["halo_feats"]`` and the step attributes).
 None of this runs in a benchmark run."""
 from __future__ import annotations
 
@@ -53,5 +56,35 @@ def unchanged(rt):
     return rt
 
 
+def no_grad_sync(rt):
+    """The gradient ``psum`` left out (``spmd`` only): each chip updates
+    from its own partition's gradient.  While the steps trace,
+    ``jax.lax.psum`` passes a pytree of more than one leaf (the gradients)
+    through unsummed; the loss and accuracy, single leaves, are still
+    summed."""
+    import jax
+    psum = jax.lax.psum
+
+    def local(x, axis_name, **kw):
+        if len(jax.tree.leaves(x)) > 1:
+            return x
+        return psum(x, axis_name, **kw)
+
+    def unsynced(fn):
+        def step(*args):
+            jax.lax.psum = local
+            try:
+                return fn(*args)
+            finally:
+                jax.lax.psum = psum
+        return step
+    for name in ("step_refresh", "step_cached", "step_pipelined"):
+        setattr(rt, name, unsynced(getattr(rt, name)))
+    return rt
+
+
 LAYOUT_FAULTS = {"half_batch": half_batch}
-RUNTIME_FAULTS = {"no_exchange": no_exchange, "unchanged": unchanged}
+RUNTIME_FAULTS = {"no_exchange": no_exchange, "unchanged": unchanged,
+                  "no_grad_sync": no_grad_sync}
+# faults that exist only across chips, by the runtime they plant into
+SPMD_FAULTS = ("no_grad_sync",)

@@ -11,6 +11,17 @@ edge weights from the graph's degrees, and runs its own Adam.
 
 A step reads the halo rows of cached tiers as they were at the last step
 that refreshed them (layers 1 and up; layer 0 reads the static features).
+
+A model module (``bench/reference/<model>.py``) gives ``init(key, dims)``
+and one of two kinds of ``layer``.  By default the reference aggregates:
+``layer(p, h_self, agg, degree)`` maps the edge-weighted sum of the source
+rows to the pre-activation, and ReLU follows every layer but the last.  A
+module that sets ``OWN_AGGREGATION = True`` aggregates itself, for weights
+that come from the layer's own activations:
+``layer(p, h, h_stale, g) -> h_out``, with :class:`EdgeView` ``g`` and its
+own activation (none where ``g.last``); ``h_stale`` holds the layer's
+inputs as the cached tiers keep them, ``None`` on layer 0 and on steps
+that refresh.
 """
 from __future__ import annotations
 
@@ -114,6 +125,29 @@ def cache_tiers(halos, n_inner, n_edges, dims, mem_gib, cpu_cache_gib,
     return out
 
 
+@dataclasses.dataclass(frozen=True)
+class EdgeView:
+    """The graph as a layer that aggregates itself reads it, inside the
+    traced step: ``src``, ``dst`` and ``w`` per edge, ``num_nodes``, and
+    whether the layer is the last."""
+    src: jax.Array
+    dst: jax.Array
+    w: jax.Array
+    stale: jax.Array      # [E] bool, the source is read from a cache tier
+    num_nodes: int
+    last: bool
+
+    def src_rows(self, x, x_stale=None):
+        """Each edge's source row of the per-node tensor ``x``, taken from
+        ``x_stale`` (the same tensor computed from the stale inputs) where
+        the edge reads a cached tier."""
+        rows = x[self.src]
+        if x_stale is None:
+            return rows
+        stale = self.stale.reshape(self.stale.shape + (1,) * (x.ndim - 1))
+        return jnp.where(stale, x_stale[self.src], rows)
+
+
 def _adam(params, grads, state, lr):
     m, v, t = state
     t = t + 1
@@ -151,6 +185,7 @@ def make_step(model, num_nodes: int, lr: float, dtype=jnp.float32,
     the forward and backward pass and ``precision`` that of its matmuls;
     the float32 master weights and Adam state stay float32.  The reference
     is float32 at ``highest``; the other settings are controls."""
+    own = getattr(model, "OWN_AGGREGATION", False)
 
     def aggregate(d, h, h_stale=None):
         rows = h[d["src"]]
@@ -163,12 +198,15 @@ def make_step(model, num_nodes: int, lr: float, dtype=jnp.float32,
         params = jax.tree.map(lambda a: a.astype(dtype), params)
         h, acts = d["x"], []
         for li, p in enumerate(params):
-            if li == 0:
-                agg = d["agg0"]
-            else:
+            if li > 0:
                 acts.append(h)
-                agg = aggregate(d, h, stale_acts[li - 1] if use_stale
-                                else None)
+            h_stale = stale_acts[li - 1] if li > 0 and use_stale else None
+            if own:
+                h = model.layer(p, h, h_stale, EdgeView(
+                    src=d["src"], dst=d["dst"], w=d["w"], stale=d["stale"],
+                    num_nodes=num_nodes, last=li == len(params) - 1))
+                continue
+            agg = d["agg0"] if li == 0 else aggregate(d, h, h_stale)
             h = model.layer(p, h, agg, d["degree"])
             if li < len(params) - 1:
                 h = jax.nn.relu(h)

@@ -35,6 +35,7 @@ class Op:
     opcode: str
     category: str         # the profiler's HLO category
     tags: frozenset
+    hlo: str = ""         # the HLO instruction's name
 
 
 @dataclasses.dataclass
@@ -130,7 +131,7 @@ def reduce_capture(path) -> Reduced:
         s = max(ev["start_ns"], lo)
         e = min(ev["start_ns"] + ev["dur_ns"], hi)
         return Op(s, e, op_name or hlo_name, opcode, cat,
-                  tags_of(op_name, opcode, cat))
+                  tags_of(op_name, opcode, cat), hlo_name)
 
     devices = []
     dev_planes = sorted((p for p in planes
@@ -155,7 +156,7 @@ def reduce_capture(path) -> Reduced:
                 pid = st.get("program_id")
                 opcode, op_name = hlo.get(pid, {}).get(n, ("", ""))
                 ops.append(Op(max(s, lo), min(e, hi), op_name or n, opcode,
-                              "", tags_of(op_name, opcode, "")))
+                              "", tags_of(op_name, opcode, ""), n))
         devices.append(ops)
     host_spans = [(max(s, lo), min(e, hi), n) for s, e, n, st in host
                   if "hlo_op" not in st and e > lo and s < hi and e > s]

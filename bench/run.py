@@ -17,7 +17,8 @@ afterwards, and
 It needs a TPU with as many chips as the cell asks for, and exits non-zero
 without one.  ``--workload cpu-tiny.capgnn`` (any traffic) rehearses the
 harness on the CPU with ``JAX_PLATFORMS=cpu``; that run gives no device
-figure.
+figure.  An ``spmd`` traffic (``cpu-tiny.p2p4``) wants one CPU device per
+partition: ``XLA_FLAGS=--xla_force_host_platform_device_count=4``.
 """
 import sys
 import time
