@@ -1071,6 +1071,11 @@ def train_capgnn(cfg: GNNConfig, runtime, xplan: ExchangePlan,
     replan_events = 0
     x_active = xplan
     dim_bytes = sum(d * dtype_bytes for d in dims)
+    edge_heads = None       # GAT's attention work per step (traced runs)
+    if tr.enabled and cfg.attention_heads:
+        stats = runtime.padding_stats()
+        edge_heads = cfg.attention_heads * (stats["edges_valid_rows"]
+                                            + stats["edges_padded_rows"])
     rows_by_worker = None   # per-worker uncached recv rows (traced runs)
     step_snap = (store.snapshot()
                  if store is not None and tr.enabled else None)
@@ -1224,7 +1229,8 @@ def train_capgnn(cfg: GNNConfig, runtime, xplan: ExchangePlan,
                 host_writeback_rows=int(sd.get("writeback_rows", 0)),
                 host_writeback_bytes=int(sd.get("writeback_bytes", 0)),
                 device_peak_bytes=device_peak_bytes(),
-                wire_rows_by_worker=rows_by_worker, **extra))
+                wire_rows_by_worker=rows_by_worker,
+                attention_edge_heads=edge_heads, **extra))
         if gd is not None and gd.cfg.checksums:
             # seal the post-step tier payloads: the digests the next
             # consuming step must still observe

@@ -102,7 +102,15 @@ class EdgeListAdj(Adjacency):
         return jax.ops.segment_sum(msgs, self.dst, num_segments=self.n_rows_)
 
     def spmm_at(self, e_vals, h):
-        msgs = h[self.src] * e_vals[:, None]
+        """``e_vals [m]`` weighs every column of an edge's message;
+        head-major ``e_vals [k, m]`` weighs each of ``h``'s ``k`` column
+        blocks (GAT's heads side by side) by its own row."""
+        if e_vals.ndim == 2:
+            scale = jnp.repeat(e_vals.T, h.shape[1] // e_vals.shape[0],
+                               axis=1)
+        else:
+            scale = e_vals[:, None]
+        msgs = h[self.src] * scale
         return jax.ops.segment_sum(msgs, self.dst, num_segments=self.n_rows_)
 
     def degree(self):
@@ -214,7 +222,8 @@ class GNNConfig:
     hidden_dim: int = 256         # paper: 256
     out_dim: int = 16
     num_layers: int = 3           # paper: 3
-    num_heads: int = 4            # GAT
+    num_heads: int = 4            # GAT: heads of a hidden layer
+    out_heads: int = 6            # GAT: heads of the output layer
     residual: bool = False
 
     @property
@@ -226,6 +235,28 @@ class GNNConfig:
     def feat_dims(self) -> list[int]:
         """Per-tier cached row widths: input features + each layer output."""
         return [self.in_dim] + [self.hidden_dim] * (self.num_layers - 1) + [self.out_dim]
+
+    def gat_heads(self, layer: int) -> tuple[int, int]:
+        """GAT: ``(heads, width of a head)`` of layer ``layer``.  A hidden
+        layer splits ``hidden_dim`` into ``num_heads`` heads, concatenated;
+        the output layer runs ``out_heads`` heads of ``out_dim``, averaged
+        (Velickovic et al. 2018, section 3.3)."""
+        if layer == self.num_layers - 1:
+            return self.out_heads, self.out_dim
+        if self.hidden_dim % self.num_heads:
+            raise ValueError(f"GAT: num_heads={self.num_heads} does not "
+                             f"divide hidden_dim={self.hidden_dim}, the "
+                             "concatenated width of a hidden layer")
+        return self.num_heads, self.hidden_dim // self.num_heads
+
+    @property
+    def attention_heads(self) -> int:
+        """Attention heads summed over the layers: GAT computes one edge
+        softmax per edge and head of each layer.  0 for the other
+        models."""
+        if self.model != "gat":
+            return 0
+        return sum(self.gat_heads(li)[0] for li in range(self.num_layers))
 
 
 def init_gnn(key, cfg: GNNConfig) -> list[dict]:
@@ -239,12 +270,13 @@ def init_gnn(key, cfg: GNNConfig) -> list[dict]:
                  "w_neigh": glorot(k2, (din, dout)),
                  "b": zeros_init(k3, (dout,))}
         elif cfg.model == "gat":
-            h = cfg.num_heads
-            dh = max(1, dout // h)
-            p = {"w": glorot(k1, (din, h * dh)),
-                 "a_src": glorot(k2, (h, dh)),
-                 "a_dst": glorot(k3, (h, dh)),
-                 "proj": glorot(key, (h * dh, dout))}
+            h, f = cfg.gat_heads(li)
+            # each head's attention vector is Glorot-uniform as an [f, 1]
+            # kernel; the bias is one per output column
+            p = {"w": glorot(k1, (din, h * f)),
+                 "a_src": glorot(k2, (h, f, 1))[..., 0],
+                 "a_dst": glorot(k3, (h, f, 1))[..., 0],
+                 "b": zeros_init(key, (dout,))}
         elif cfg.model == "gin":
             p = {"w1": glorot(k1, (din, dout)), "b1": zeros_init(k2, (dout,)),
                  "w2": glorot(k3, (dout, dout)), "b2": zeros_init(key, (dout,)),
@@ -255,12 +287,54 @@ def init_gnn(key, cfg: GNNConfig) -> list[dict]:
     return params
 
 
+def _edge_softmax(z: jnp.ndarray, a_src: jnp.ndarray, a_dst: jnp.ndarray,
+                  adj: EdgeListAdj) -> jnp.ndarray:
+    """GAT's attention coefficients, head-major ``[heads, E]``: per head
+    ``h`` and edge ``u -> v``, ``e = LeakyReLU_0.2(a_src[h] . z_h[u] +
+    a_dst[h] . z_h[v])``, normalised by a softmax over ``v``'s in-edges.
+    ``z`` holds the heads side by side, ``[rows, heads * width]``.  The
+    per-vertex maximum is a shift the softmax cancels, so no gradient
+    flows through it.  A padding edge (destination ``n_rows``, as the
+    mesh shards' edge rectangle holds) reads a neutral maximum and sum:
+    its coefficient stays finite, and the sums drop it."""
+    heads, width = a_src.shape
+    alpha = []
+    for h in range(heads):
+        z_h = z[:, h * width:(h + 1) * width]
+        logit = jax.nn.leaky_relu((z_h @ a_src[h])[adj.src]
+                                  + (z_h[:adj.n_rows] @ a_dst[h])[adj.dst],
+                                  0.2)
+        top = jax.lax.stop_gradient(jax.ops.segment_max(
+            logit, adj.dst, num_segments=adj.n_rows))
+        ex = jnp.exp(logit - top.at[adj.dst].get(mode="fill",
+                                                 fill_value=0.0))
+        den = jax.ops.segment_sum(ex, adj.dst, num_segments=adj.n_rows)
+        alpha.append(ex / den.at[adj.dst].get(mode="fill", fill_value=1.0))
+    return jnp.stack(alpha)
+
+
+def head_sum(adj: EdgeListAdj, alpha: jnp.ndarray,
+             z: jnp.ndarray) -> jnp.ndarray:
+    """``adj.spmm_at(alpha, z)``, the attention-weighted sum of heads side
+    by side, whose backward recomputes the gathered source rows from the
+    saved ``alpha`` and ``z``: kept, one head's ``[E, width]`` rows would
+    outweigh the layer."""
+    return jax.checkpoint(adj.spmm_at)(alpha, z)
+
+
 def _layer_apply(cfg: GNNConfig, p: dict, adj: Adjacency,
                  h_local: jnp.ndarray, n_inner: int, is_last: bool) -> jnp.ndarray:
     """One layer, its work under the two scopes of a layer
     (:data:`repro.obs.annotations.LAYER_PARTS`): ``aggregate``, the
     adjacency product with SAGE's degree normalisation and GAT's edge
-    softmax, and ``transform``, the dense work and the activation."""
+    softmax (its own ``edge_softmax`` scope inside ``aggregate``), and
+    ``transform``, the dense work and the activation.
+
+    GAT (Velickovic et al. 2018): ``z = h W``, the edge softmax per head,
+    each head's coefficient-weighted sum of source rows, then a bias per
+    output column.  A hidden layer concatenates its heads, adds its input
+    where the widths match (the identity skip) and applies ELU; the output
+    layer averages its heads."""
     if cfg.model == "gcn":
         with device_scope("aggregate"):
             agg = adj.spmm(h_local)
@@ -278,25 +352,29 @@ def _layer_apply(cfg: GNNConfig, p: dict, adj: Adjacency,
                 f"GAT's edge softmax needs flat edge ids, which the "
                 f"{type(adj).__name__} backend does not expose — build the "
                 "adjacency/runtime with backend='edges' for GAT.")
+        heads, width = p["a_src"].shape
         with device_scope("transform"):
-            h_heads = (h_local @ p["w"]).reshape(h_local.shape[0],
-                                                 p["a_src"].shape[0], -1)
+            z = h_local @ p["w"]                      # [rows, heads * width]
         with device_scope("aggregate"):
-            e_src = jnp.einsum("nhd,hd->nh", h_heads, p["a_src"])
-            e_dst = jnp.einsum("nhd,hd->nh", h_heads, p["a_dst"])
-            logits = jax.nn.leaky_relu(e_src[adj.src] + e_dst[adj.dst], 0.2)
-            # segment softmax over incoming edges of each inner vertex
-            seg_max = jax.ops.segment_max(logits, adj.dst,
-                                          num_segments=adj.n_rows)
-            ex = jnp.exp(logits - seg_max[adj.dst])
-            denom = jax.ops.segment_sum(ex, adj.dst, num_segments=adj.n_rows)
-            att = ex / jnp.maximum(denom[adj.dst], 1e-9)
-            outs = []
-            for hh in range(att.shape[1]):
-                outs.append(adj.spmm_at(att[:, hh], h_heads[:, hh, :]))
-            agg = jnp.concatenate(outs, axis=-1)
+            with device_scope("edge_softmax"):
+                alpha = _edge_softmax(z, p["a_src"], p["a_dst"], adj)
+            if is_last:
+                # the narrow output heads gathered as one [rows, H*C] row
+                agg = head_sum(adj, alpha, z)
+            else:
+                agg = jnp.concatenate(
+                    [head_sum(adj, alpha[h], z[:, h * width:(h + 1) * width])
+                     for h in range(heads)], axis=-1)
         with device_scope("transform"):
-            z = agg @ p["proj"]
+            if is_last:
+                z = agg[:, :width]
+                for h in range(1, heads):
+                    z = z + agg[:, h * width:(h + 1) * width]
+                z = z / heads + p["b"]
+            else:
+                z = agg + p["b"]
+                if h_local.shape[1] == z.shape[1]:
+                    z = z + h_local[:n_inner]         # identity skip
     elif cfg.model == "gin":
         with device_scope("aggregate"):
             agg = adj.spmm(h_local)
@@ -307,7 +385,7 @@ def _layer_apply(cfg: GNNConfig, p: dict, adj: Adjacency,
         raise ValueError(cfg.model)
     if not is_last:
         with device_scope("transform"):
-            z = jax.nn.relu(z)
+            z = jax.nn.elu(z) if cfg.model == "gat" else jax.nn.relu(z)
     return z
 
 

@@ -34,11 +34,14 @@ PHASES = {
     "grad_sync": "SPMD: the psum of the loss, gradients and accuracy",
     "optimizer": "the optimizer update",
 }
-# the parts of a ``layer{i}`` phase
+# the parts of a ``layer{i}`` phase; ``edge_softmax`` opens inside
+# ``aggregate``, so the aggregation's time includes it
 LAYER_PARTS = {
     "aggregate": "the adjacency product on every backend, SAGE's degree "
                  "normalisation, GAT's edge softmax",
     "transform": "the matmuls, bias, GIN's MLP and the activation",
+    "edge_softmax": "GAT: the attention logits, their per-vertex maximum, "
+                    "exponentials, sums and normalisation",
 }
 _NAMES = (PHASES.keys() - {"layer{i}"}) | LAYER_PARTS.keys()
 _LAYER = re.compile(r"layer\d+")

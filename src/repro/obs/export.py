@@ -22,7 +22,8 @@ _COUNTER_FIELDS = ("wire_bytes", "wire_rows_uncached", "wire_rows_local",
                    "wire_rows_global", "host_fetch_rows",
                    "host_fetch_bytes", "host_writeback_bytes",
                    "cache_hit_rate", "planner_hit_rate", "drift",
-                   "device_peak_bytes", "queries", "hot_hits", "host_hits",
+                   "device_peak_bytes", "attention_edge_heads",
+                   "queries", "hot_hits", "host_hits",
                    "fresh_recomputes",
                    "faults_injected", "fetch_errors", "fetch_retries",
                    "fetch_stale_reuse", "slow_fetches",

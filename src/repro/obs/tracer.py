@@ -100,6 +100,9 @@ class StepCounters:
     host_writeback_bytes: int = 0
     device_peak_bytes: int | None = None
     wire_rows_by_worker: list | None = None  # per-worker uncached recv rows
+    # GAT: edge x head pairs of the step's attention, summed over layers
+    # (edge slots the aggregation runs); None for the other models
+    attention_edge_heads: int | None = None
     # serve-side records (kind="serve", one per micro-batch); None on
     # training records so the exporter emits no empty counter tracks
     queries: int | None = None
